@@ -17,6 +17,8 @@ def main(argv=None):
     ap.add_argument("--only", default=None,
                     help="comma-separated bench names")
     args = ap.parse_args(argv)
+    from repro import use_compile_cache
+    use_compile_cache()
 
     from benchmarks import (async_throughput, fault_sweep, fig2_convergence,
                             kernel_bench, noise_sweep, population_scale,

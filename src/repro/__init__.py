@@ -20,6 +20,26 @@ if "JAX_THREEFRY_PARTITIONABLE" not in _os.environ:
 #: environment override for the package-wide base seed (see rng_key)
 SEED_ENV = "REPRO_SEED"
 
+#: the persistent compile cache when JAX_COMPILATION_CACHE_DIR is unset
+CACHE_DIR = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.dirname(
+        _os.path.abspath(__file__)))), ".jax_cache")
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache; returns its directory.
+
+    Entry points call this (never on import, so tests keep JAX's default).
+    A set ``JAX_COMPILATION_CACHE_DIR`` is JAX's own setting and is left
+    alone.  Otherwise the cache is :data:`CACHE_DIR`, one fixed path in
+    the checkout: a directory that moved from run to run would never hit.
+    """
+    path = _os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    _jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    return CACHE_DIR
+
 
 def rng_key(seed=None) -> "_jax.Array":
     """The approved seed factory (gflint GFL001).

@@ -334,7 +334,12 @@ def worker_process_main(p: int, prob_dict: dict, spec_str: str,
     the child.  In socket mode the worker binds an ephemeral port and
     reports its address in the hello — the coordinator's namebook is the
     only place addresses accumulate.
+
+    The worker is numpy; it is pinned to the CPU backend before anything
+    can create another, so it never contends with its parent for a chip.
     """
+    import jax
+    jax.config.update("jax_platforms", "cpu")
     prob = FleetProblem.from_dict(prob_dict)
     spec = parse_fleet_spec(spec_str)
     name = worker_name(p)

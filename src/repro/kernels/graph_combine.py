@@ -50,14 +50,16 @@ def _combine_kernel(*refs, has_g: bool, has_gate: bool):
     if has_gate:
         gate = gate_ref[...].astype(jnp.float32)          # [P, 1]
         psi = gate * psi + (1.0 - gate) * cache_ref[...].astype(jnp.float32)
+    # full f32 passes on the MXU: the eq.-24 noise only cancels to f32
+    # rounding if the mix is f32 (a bf16 pass would leak ~2^-9 of g into
+    # the centroid); P is tiny, so the extra passes cost nothing here
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
     if has_g:
         g = g_ref[...].astype(jnp.float32)
-        mixed = jnp.dot(a_t, psi + g,
-                        preferred_element_type=jnp.float32)
-        out_ref[...] = (mixed - g).astype(out_ref.dtype)
+        out_ref[...] = (dot(a_t, psi + g) - g).astype(out_ref.dtype)
     else:
-        mixed = jnp.dot(a_t, psi, preferred_element_type=jnp.float32)
-        out_ref[...] = mixed.astype(out_ref.dtype)
+        out_ref[...] = dot(a_t, psi).astype(out_ref.dtype)
 
 
 def graph_combine(a_t: jax.Array, psi: jax.Array,

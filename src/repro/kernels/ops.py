@@ -18,16 +18,18 @@ which collapsed to pathological 1-wide grids for odd/prime D; now the block
 is always a 128-multiple and D pads to it (regression-tested on D=509).
 
 Block autotuning: ``block_d`` candidates {128, 256, 512, 1024} that tile
-the padded model dim are timed once per (op, shape, dtype) and the winner
-is cached for the process (``choose_block``); set ``REPRO_KERNEL_AUTOTUNE=0``
-to skip timing and take the largest candidate.  Timing runs eagerly on
-dummy zeros at trace time, so jitted callers autotune exactly once per
-shape.
+the padded model dim are timed once per (op, shape, dtype) on the device
+and the winner is cached for the process (``choose_block``); set
+``REPRO_KERNEL_AUTOTUNE=0`` to skip timing and take the largest candidate.
+Timing runs eagerly on dummy zeros at trace time (in an eval context,
+out of the caller's trace), so jitted callers autotune once per shape.
+Interpret mode is never timed: its timings say nothing about a chip.
 """
 from __future__ import annotations
 
 import os
 import time
+import warnings
 from functools import partial
 
 import jax
@@ -135,33 +137,45 @@ def choose_block(op: str, d: int, *, shape_key: tuple = (),
 
     When more than one candidate tiles the padded dim and ``make_timed``
     is given (``make_timed(block_d, d_pad) -> zero-arg callable`` running
-    the kernel on dummy data), each candidate is timed once — warmup call
-    then one measured call — and the winner is cached per
-    ``(op, d_pad, interpret, *shape_key)`` for the process lifetime."""
+    the kernel on dummy data), each candidate is compiled and timed once
+    on the device — warmup call then one measured call — and the winner
+    is cached per ``(op, d_pad, interpret, *shape_key)`` for the process
+    lifetime.  A candidate that fails is reported as a warning; if none
+    runs, the error is raised."""
     from repro.telemetry import trace_span
     cands, d_pad = block_candidates(d)
     key = (op, d_pad, interpret) + tuple(shape_key)
     if key in _AUTOTUNE_CACHE:
         return _AUTOTUNE_CACHE[key], d_pad
     autotuned = False
-    if (len(cands) == 1 or make_timed is None
+    if (len(cands) == 1 or make_timed is None or interpret
             or os.environ.get("REPRO_KERNEL_AUTOTUNE", "1") == "0"):
         block = cands[-1]
     else:
         autotuned = True
-        best = (float("inf"), cands[-1])
+        times, errors = {}, {}
+        # callers are jitted: outside an eval context the candidates would
+        # only be traced into the caller's program, never run.  (Not
+        # ensure_compile_time_eval: it folds the kernel body's constants,
+        # which pallas_call refuses.)
         with trace_span(f"autotune:{op}", d_pad=d_pad,
-                        candidates=len(cands)):
+                        candidates=len(cands)), jax.core.eval_context():
             for c in cands:
                 try:
                     fn = make_timed(c, d_pad)
                     jax.block_until_ready(fn())      # compile + warmup
                     t0 = time.perf_counter()
                     jax.block_until_ready(fn())
-                    best = min(best, (time.perf_counter() - t0, c))
-                except Exception:                    # candidate infeasible
-                    continue
-        block = best[1]
+                    times[c] = time.perf_counter() - t0
+                except Exception as e:  # noqa: BLE001 - reported below
+                    errors[c] = e
+                    warnings.warn(f"{op}: block_d={c} (d_pad={d_pad}) "
+                                  f"failed: {type(e).__name__}: {e}")
+        if not times:
+            raise RuntimeError(
+                f"{op}: no block_d candidate in {cands} runs at "
+                f"d_pad={d_pad}") from errors[cands[-1]]
+        block = min(times, key=times.get)
     _AUTOTUNE_CACHE[key] = block
     _emit_kernel(op=op, backend="pallas", block_d=block, d_pad=d_pad,
                  interpret=int(interpret), autotuned=int(autotuned))
@@ -295,8 +309,9 @@ def graph_combine(A: jax.Array, psi: jax.Array, g: jax.Array | None = None,
     if backend == "ref":
         psi = apply_gate(psi, gate, cache)
         if g is None:
-            mixed = (jnp.asarray(A).T.astype(jnp.float32)
-                     @ psi.astype(jnp.float32))
+            mixed = jnp.matmul(jnp.asarray(A).T.astype(jnp.float32),
+                               psi.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST)
             return mixed.astype(psi.dtype)
         return _ref.graph_combine_ref(jnp.asarray(A).T, psi, g)
 

@@ -16,8 +16,9 @@ from repro.kernels.secure_agg import pair_stream
 def graph_combine_ref(a_t: jax.Array, psi: jax.Array, g: jax.Array
                       ) -> jax.Array:
     """out = A^T (psi + g) - g  (eq. 8 with eq. 24 noise structure)."""
-    mixed = (a_t.astype(jnp.float32)
-             @ (psi + g).astype(jnp.float32))
+    mixed = jnp.matmul(a_t.astype(jnp.float32),
+                       (psi + g).astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
     return (mixed - g.astype(jnp.float32)).astype(psi.dtype)
 
 

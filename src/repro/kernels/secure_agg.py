@@ -53,7 +53,10 @@ def pair_stream(pair_id: jax.Array, idx: jax.Array, seed: jax.Array,
                   ^ _hash_u32(pid * jnp.uint32(0x9E3779B9)
                               + jnp.uint32(seed)))
     # top 24 bits -> uniform in [0,1) with exact float32 representation
-    u = (h >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    # (through int32, which holds 24 bits exactly: Mosaic has no
+    # uint32 -> float32 cast)
+    u = ((h >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32)
+         * jnp.float32(1.0 / (1 << 24)))
     return (2.0 * u - 1.0) * scale
 
 
@@ -64,8 +67,8 @@ def net_mask_stream(k: jax.Array, idx: jax.Array, seed: jax.Array,
 
     mask_k = sum_{j>k} +PRG(pair(k,j)) + sum_{j<k} -PRG(pair(j,k)),
     optionally restricted to pairs whose peer j is alive (``alive`` [L]
-    bool) — dead peers' streams never arrive, matching the Bonawitz
-    orphan-repair semantics of the reference path.
+    or [L, 1] bool) — dead peers' streams never arrive, matching the
+    Bonawitz orphan-repair semantics of the reference path.
 
     Vectorized over all L peers, so a ``fori_loop`` over k costs O(1) trace
     size; the pair enumeration matches the row-major (a < b) ordering of
@@ -81,7 +84,7 @@ def net_mask_stream(k: jax.Array, idx: jax.Array, seed: jax.Array,
     sgn = jnp.where(j > k, jnp.float32(1.0), jnp.float32(-1.0))
     m = jnp.where(j == k, 0.0, sgn) * s
     if alive is not None:
-        m = jnp.where(alive[:, None], m, 0.0)
+        m = jnp.where(alive.reshape(L, 1), m, 0.0)
     return jnp.sum(m, axis=0, keepdims=True)                  # [1, bd]
 
 

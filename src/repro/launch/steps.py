@@ -44,11 +44,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:                                    # jax >= 0.4.x moved this around
-    _shard_map = jax.shard_map
-except AttributeError:                  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro import rng_key
 from repro.configs.base import GFLConfig, InputShape, ModelConfig
 from repro.core.privacy.mechanism import RoundContext, mechanism_for
@@ -97,16 +92,18 @@ def _dense_combine(A, psi, g, cancel: bool = True):
     `cancel` applies the graph-homomorphic self-subtraction (eq. 24); it is
     driven by the mechanism's ``noise_profile().server_cancels_exactly``.
     """
+    def combine(x):   # f32 mix: the eq.-24 noise cancels to f32 rounding
+        return jnp.einsum("mp,m...->p...", A.astype(jnp.float32),
+                          x.astype(jnp.float32),
+                          precision=jax.lax.Precision.HIGHEST)
+
     def mix(x, noise):
-        mixed = jnp.einsum("mp,m...->p...", A.astype(jnp.float32),
-                           (x + noise).astype(jnp.float32))
+        mixed = combine(x + noise)
         if cancel:
             mixed = mixed - noise.astype(jnp.float32)
         return mixed.astype(x.dtype)
     if g is None:
-        return jax.tree.map(
-            lambda x: jnp.einsum("mp,m...->p...", A.astype(jnp.float32),
-                                 x.astype(jnp.float32)).astype(x.dtype), psi)
+        return jax.tree.map(lambda x: combine(x).astype(x.dtype), psi)
     return jax.tree.map(mix, psi, g)
 
 
@@ -178,7 +175,7 @@ def _make_shardmap_combine(mesh, cfg: ModelConfig, gfl: GFLConfig,
     def combine_fn(noisy_psi, Aj):
         return jax.tree.map(lambda x: _rotate_combine_leaf(x, Aj), noisy_psi)
 
-    return _shard_map(combine_fn, mesh=mesh, in_specs=(specs, P()),
+    return jax.shard_map(combine_fn, mesh=mesh, in_specs=(specs, P()),
                          out_specs=specs)
 
 
@@ -261,7 +258,7 @@ def _make_sparse_combine(mesh, cfg: ModelConfig, gfl: GFLConfig,
     def combine_fn(noisy_psi, Aj):
         return jax.tree.map(lambda x: _combine_leaf(x, Aj), noisy_psi)
 
-    return _shard_map(combine_fn, mesh=mesh, in_specs=(specs, P()),
+    return jax.shard_map(combine_fn, mesh=mesh, in_specs=(specs, P()),
                          out_specs=specs)
 
 
@@ -567,12 +564,23 @@ def make_train_step(model: Model, gfl: GFLConfig, mesh,
 
 
 def init_train_state(model: Model, gfl: GFLConfig, mesh, key) -> TrainState:
-    """Per-server replicated init (all servers start from the same point)."""
+    """Per-server replicated init (all servers start from the same point).
+
+    The P server copies are built in place with the train step's own
+    parameter shardings, so each device holds only its servers' shards."""
     Pn = num_servers(mesh)
-    params = model.init(key)
-    params = jax.tree.map(
-        lambda x: jnp.broadcast_to(x[None], (Pn,) + x.shape), params)
-    return TrainState(params, jnp.zeros((), jnp.int32), key)
+    _, shardings = params_specs(model, mesh, gfl_train=True,
+                                client_parallel=gfl.client_parallel)
+
+    def init(k):
+        return jax.tree.map(
+            lambda x: jnp.broadcast_to(x[None], (Pn,) + x.shape),
+            model.init(k))
+
+    params = jax.jit(init, out_shardings=shardings)(key)
+    rep = NamedSharding(mesh, P())
+    return TrainState(params, jax.device_put(jnp.zeros((), jnp.int32), rep),
+                      jax.device_put(key, rep))
 
 
 # ---------------------------------------------------------------------------

@@ -1,13 +1,20 @@
 """Training launcher: GFL training of any --arch on a mesh.
 
-On real hardware this runs the production mesh; on CPU it runs reduced
-configs on a forced-device test mesh (--devices) so the full path —
-sharded params, client scans, sparse combine collectives, checkpointing,
-privacy accounting — is exercised end to end.
+By default the mesh fits the devices present: one GFL server per device on
+``data`` and ``model`` = 1, so the same command runs on one chip, on a
+four-chip host, or on forced CPU devices.  ``--mesh production`` (16x16)
+and ``production-multipod`` name the pod layouts the dry run compiles for;
+``--mesh 2x4`` style specs pick an explicit (data x model) layout.
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+    python -m repro.launch.train --arch smollm-135m --steps 20 \
+        --seq 1024 --clients 4 --privacy hybrid --combine dense
+
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
     PYTHONPATH=src python -m repro.launch.train --arch smollm-135m \
         --reduced --mesh 2x4 --steps 20
+
+``main`` returns the final state, the per-step losses and set-up timings,
+so a caller (``chip_smoke.py``) can check them.
 """
 from __future__ import annotations
 
@@ -18,14 +25,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import rng_key
+from repro import rng_key, use_compile_cache
 from repro.checkpoint import save_checkpoint
 from repro.configs.base import GFLConfig
 from repro.configs.registry import get_config
 from repro.core.privacy.mechanism import mechanism_for
 from repro.data import TokenStream, federated_token_batches
 from repro.launch import steps as steps_lib
-from repro.launch.mesh import make_production_mesh, make_test_mesh, num_servers
+from repro.launch.mesh import (make_device_mesh, make_production_mesh,
+                               make_test_mesh, num_servers)
 from repro.models import Model
 
 
@@ -40,8 +48,10 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--mesh", default="production",
-                    help="'production', 'production-multipod' or e.g. '2x4'")
+    ap.add_argument("--mesh", default="devices",
+                    help="'devices' (one server per device, the default), "
+                         "'production', 'production-multipod' or e.g. "
+                         "'2x4'")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--clients", type=int, default=2)
@@ -86,13 +96,16 @@ def main(argv=None):
                          "repro.telemetry.inspect)")
     ap.add_argument("--checkpoint", default=None)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     model = Model(cfg)
 
-    if args.mesh == "production":
+    if args.mesh == "devices":
+        mesh = make_device_mesh()
+    elif args.mesh == "production":
         mesh = make_production_mesh()
     elif args.mesh == "production-multipod":
         mesh = make_production_mesh(multi_pod=True)
@@ -168,6 +181,7 @@ def main(argv=None):
             state = steps_lib.init_train_state(model, gfl_cfg, mesh,
                                                rng_key())
         t0 = time.time()
+        losses, first_step_s, t1 = [], None, None
         # cohort selection stream stays decoupled from the model-init seed
         sel_key = rng_key(1234)
         for i in range(args.steps):
@@ -193,6 +207,11 @@ def main(argv=None):
                     print(f"  round {i}: spectral gap {real.gap:.3f}")
             else:
                 state, metrics = step(state, batch, cohort_weights=weights)
+            losses.append(metrics["loss"])
+            if i == 0:   # compile + one step; the rest is steady state
+                jax.block_until_ready(state)
+                t1 = time.time()
+                first_step_s = t1 - t0
             # one ledger release per protocol round, charged at THIS
             # round's realized rate (a running mean would under-report the
             # spend whenever q varies round to round — f(q) is convex-ish
@@ -226,11 +245,17 @@ def main(argv=None):
                            f"rel {min(rel)}-{max(rel)}")
                 print(f"step {i:4d} loss {float(metrics['loss']):.4f} "
                       f"eps {eps:.1f}{amp} ({time.time()-t0:.0f}s)")
+        jax.block_until_ready(state)
+        step_s = ((time.time() - t1) / (args.steps - 1)
+                  if args.steps > 1 else None)
     if args.checkpoint:
         save_checkpoint(args.checkpoint,
                         jax.tree.map(lambda x: x[0], state.params),
                         step=args.steps)
         print(f"saved consensus checkpoint to {args.checkpoint}")
+    return {"state": state,
+            "losses": np.asarray(jax.device_get(losses), np.float32),
+            "first_step_s": first_step_s, "step_s": step_s}
 
 
 if __name__ == "__main__":

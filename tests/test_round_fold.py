@@ -152,6 +152,44 @@ def test_autotune_caches_per_shape():
     assert block in (128, 256, 512, 1024)
 
 
+@pytest.mark.parametrize("mode", ["none", "mask"])
+def test_autotune_runs_candidates_inside_jit(mode):
+    """The autotuner is reached while a jitted caller traces; its
+    candidates must really run (not be traced into the caller), and a
+    kernel body with constants must still trace."""
+    from repro.kernels import round_fold as rf
+    ops.clear_autotune_cache()
+    ran = []
+
+    def make_timed(block, d_pad):
+        w, g = jnp.zeros((2, d_pad)), jnp.zeros((2, 8, d_pad))
+        s = jnp.ones((2, 8))
+        seeds = jnp.zeros((2,), jnp.uint32) if mode == "mask" else None
+
+        def fn():
+            out = rf.fold_apply(w, g, s, s, s, mode=mode, sigma=0.5,
+                                seeds=seeds, block_d=block, interpret=True)
+            ran.append(isinstance(out, jax.core.Tracer))
+            return out
+        return fn
+
+    jax.jit(lambda x: x + ops.choose_block(
+        "probe", 1000, shape_key=(mode,), make_timed=make_timed)[0])(0)
+    assert len(ran) == 2 * len(ops.block_candidates(1000)[0])
+    assert not any(ran)
+
+
+def test_autotune_raises_when_no_candidate_runs():
+    ops.clear_autotune_cache()
+
+    def make_timed(block, d_pad):
+        raise ValueError(f"block {block} refused")
+
+    with pytest.warns(UserWarning, match="refused"), \
+            pytest.raises(RuntimeError, match="no block_d candidate"):
+        ops.choose_block("probe", 1000, make_timed=make_timed)
+
+
 # ------------------------------------------------- gated combine (events)
 
 
