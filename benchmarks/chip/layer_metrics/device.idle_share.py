@@ -1,0 +1,10 @@
+"""Share of the traced window in which no op ran on the device: one minus
+the union of the device's op intervals over the window, averaged over the
+chips used."""
+
+
+def read(rec):
+    t = rec["trace"]
+    if t is None or t.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
