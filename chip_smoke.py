@@ -58,25 +58,20 @@ LOSS_ATOL = 1e-2        # bf16 params: the combines differ by rounding only
 CENTROID_ATOL = 5e-3    # as tests/test_dryrun.py asserts on CPU
 
 
-class CompileClock:
-    """Sums JAX's own trace, lowering and backend-compile durations."""
+def compile_snapshot() -> dict:
+    """JAX's trace, lowering and backend-compile seconds so far in this
+    process, with the counts of compiles and of persistent-cache loads,
+    from the program's own profile counters."""
+    from repro.telemetry.profile import compile_counters, ensure_listener
+    ensure_listener()
+    return compile_counters()
 
-    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration")
 
-    def __init__(self):
-        from jax import monitoring
-        self.seconds = 0.0
-        monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event, duration, **_):
-        if event in self.EVENTS:
-            self.seconds += duration
-
-    def lap(self) -> float:
-        s, self.seconds = self.seconds, 0.0
-        return s
+def compiled_since(before: dict) -> str:
+    after = compile_snapshot()
+    return (f"compile {after['compile_s'] - before['compile_s']:.2f}s, "
+            f"{after['compiles'] - before['compiles']} compiled, "
+            f"{after['cache_loads'] - before['cache_loads']} from the cache")
 
 
 @contextlib.contextmanager
@@ -106,7 +101,7 @@ def _rel_gap(a, b) -> float:
     return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
 
 
-def phase_fused_round(clock: CompileClock, P, K, L, batch, M, iters,
+def phase_fused_round(P, K, L, batch, M, iters,
                       expect=("pallas", False)) -> None:
     """(a): the population engine's scan path with the fused kernels."""
     import jax
@@ -154,7 +149,7 @@ def phase_fused_round(clock: CompileClock, P, K, L, batch, M, iters,
         seen.clear()
         ops._resolve = spy
         try:
-            clock.lap()
+            before = compile_snapshot()
             res_k, p_k, secs = run(privacy, iters)
             # the round program as the scan body runs it, compiled alone
             A = jnp.asarray(base_combination_matrix(cfg, P), jnp.float32)
@@ -173,7 +168,7 @@ def phase_fused_round(clock: CompileClock, P, K, L, batch, M, iters,
         blocks = {k[0]: v for k, v in ops._AUTOTUNE_CACHE.items()
                   if k[2] == expect[1]}
         print(f"[a] {privacy:7s} mode={mode:7s} pallas run {secs:.2f}s "
-              f"(compile {clock.lap():.2f}s); resolve={sorted(seen)}; "
+              f"({compiled_since(before)}); resolve={sorted(seen)}; "
               f"tpu_custom_call x{hlo.count('tpu_custom_call')}; "
               f"block_d={blocks}")
         if seen != {expect}:
@@ -217,30 +212,30 @@ def phase_fused_round(clock: CompileClock, P, K, L, batch, M, iters,
         raise AssertionError("; ".join(failures))
 
 
-def _train(clock: CompileClock, argv: list) -> dict:
+def _train(argv: list) -> dict:
     import jax
     from repro.launch import train
-    clock.lap()
+    before = compile_snapshot()
     out = train.main(argv)
-    out["compile_s"] = clock.lap()
+    out["compiled"] = compiled_since(before)
     stats = jax.devices()[0].memory_stats() or {}
     out["peak_bytes"] = stats.get("peak_bytes_in_use")
     return out
 
 
 def _report(tag: str, name: str, out: dict) -> None:
-    print(f"[{tag}] {name}: losses {out['losses'].tolist()}; compile "
-          f"{out['compile_s']:.2f}s; first step {out['first_step_s']:.2f}s; "
+    print(f"[{tag}] {name}: losses {out['losses'].tolist()}; "
+          f"{out['compiled']}; first step {out['first_step_s']:.2f}s; "
           f"step {out['step_s']!r}s after warm-up; peak_bytes_in_use "
           f"{out['peak_bytes']} (device 0, whole process)")
 
 
-def phase_trainer(clock: CompileClock, train_args=TRAIN_ARGS) -> None:
+def phase_trainer(train_args=TRAIN_ARGS) -> None:
     """(b): smollm-135m at published width, with and without the kernel."""
     import numpy as np
     runs = {}
     for name, extra in (("einsum", []), ("kernels", ["--use-kernels"])):
-        runs[name] = out = _train(clock, train_args + ["--combine", "dense"]
+        runs[name] = out = _train(train_args + ["--combine", "dense"]
                                   + extra)
         _report("b", name, out)
     failures = []
@@ -256,7 +251,7 @@ def phase_trainer(clock: CompileClock, train_args=TRAIN_ARGS) -> None:
         raise AssertionError("; ".join(failures))
 
 
-def phase_four_chip(clock: CompileClock, train_args=TRAIN_ARGS) -> None:
+def phase_four_chip(train_args=TRAIN_ARGS) -> None:
     """(c): one server per chip; sparse against rotate and dense."""
     import jax
     import numpy as np
@@ -264,7 +259,7 @@ def phase_four_chip(clock: CompileClock, train_args=TRAIN_ARGS) -> None:
     runs = {}
     failures = []
     for impl in ("sparse", "rotate", "dense"):
-        out = _train(clock, train_args + ["--combine", impl])
+        out = _train(train_args + ["--combine", impl])
         _report("c", impl, out)
         leaves = jax.tree.leaves(out["state"].params)
         for leaf in leaves:
@@ -325,17 +320,16 @@ def main(argv=None) -> int:
     from repro import use_compile_cache
     print(f"device {dev.device_kind} x{len(devices)}; jax {jax.__version__}; "
           f"compile cache {use_compile_cache()}")
-    clock = CompileClock()
     if args.four_chip:
         phases = [("c four-chip combines", phase_four_chip)]
     else:
-        phases = [("a fused round", lambda c: phase_fused_round(c, **POP)),
+        phases = [("a fused round", lambda: phase_fused_round(**POP)),
                   ("b trainer", phase_trainer)]
     failed = []
     for name, fn in phases:
         t = time.time()
         try:
-            fn(clock)
+            fn()
         except Exception:  # noqa: BLE001 - reported, and the run fails
             traceback.print_exc()
             failed.append(name)

@@ -42,24 +42,25 @@ class TokenStream:
         return jnp.asarray(rng.permutation(self.vocab), jnp.int32)
 
     def sample(self, key, batch: int, seq_len: int) -> jax.Array:
-        succ = self._succ_table()
-        k1, k2, k3 = jax.random.split(key, 3)
-        # zipf via exponential rank trick
-        ranks = jnp.arange(1, self.vocab + 1, dtype=jnp.float32)
-        logits = -jnp.log(ranks)
-        draws = jax.random.categorical(k1, logits, shape=(batch, seq_len))
-        use_bigram = jax.random.bernoulli(k2, self.bigram_frac,
-                                          (batch, seq_len))
+        with jax.named_scope("gfl.input"):
+            succ = self._succ_table()
+            k1, k2, k3 = jax.random.split(key, 3)
+            # zipf via exponential rank trick
+            ranks = jnp.arange(1, self.vocab + 1, dtype=jnp.float32)
+            logits = -jnp.log(ranks)
+            draws = jax.random.categorical(k1, logits, shape=(batch, seq_len))
+            use_bigram = jax.random.bernoulli(k2, self.bigram_frac,
+                                              (batch, seq_len))
 
-        def step(prev, inp):
-            d, ub = inp
-            tok = jnp.where(ub, succ[prev], d)
-            return tok, tok
+            def step(prev, inp):
+                d, ub = inp
+                tok = jnp.where(ub, succ[prev], d)
+                return tok, tok
 
-        first = draws[:, 0]
-        _, toks = jax.lax.scan(step, first,
-                               (draws[:, 1:].T, use_bigram[:, 1:].T))
-        return jnp.concatenate([first[:, None], toks.T], axis=1)
+            first = draws[:, 0]
+            _, toks = jax.lax.scan(step, first,
+                                   (draws[:, 1:].T, use_bigram[:, 1:].T))
+            return jnp.concatenate([first[:, None], toks.T], axis=1)
 
 
 def make_batch(stream: TokenStream, key, batch: int, seq_len: int) -> dict:
@@ -77,16 +78,21 @@ def federated_token_batches(stream: TokenStream, seed: int, step: int,
     names the *population* client behind each cohort slot — a virtual
     client keeps the same data chain whichever round (and slot) a
     :class:`~repro.core.population.CohortScheduler` samples it into;
-    the default is the positional identity ``client_ids[p, l] = l``."""
-    base = jax.random.fold_in(jax.random.PRNGKey(seed), step)
-    if client_ids is not None:
-        client_ids = np.asarray(client_ids)
+    the default is the positional identity ``client_ids[p, l] = l``.
 
-    def client_batch(p, l):
-        cid = l if client_ids is None else int(client_ids[p, l])
-        k = jax.random.fold_in(jax.random.fold_in(base, p), cid)
-        return make_batch(stream, k, per_client, seq_len)
+    The build runs in the ``gfl.input`` span (``round=step``) and its
+    device work under the ``gfl.input`` scope."""
+    from repro.telemetry import trace_span
+    with trace_span("gfl.input", round=step), jax.named_scope("gfl.input"):
+        base = jax.random.fold_in(jax.random.PRNGKey(seed), step)
+        if client_ids is not None:
+            client_ids = np.asarray(client_ids)
 
-    batches = [[client_batch(p, l) for l in range(L)] for p in range(P)]
-    return jax.tree.map(lambda *xs: jnp.stack(xs).reshape(
-        P, L, *xs[0].shape), *[b for row in batches for b in row])
+        def client_batch(p, l):
+            cid = l if client_ids is None else int(client_ids[p, l])
+            k = jax.random.fold_in(jax.random.fold_in(base, p), cid)
+            return make_batch(stream, k, per_client, seq_len)
+
+        batches = [[client_batch(p, l) for l in range(L)] for p in range(P)]
+        return jax.tree.map(lambda *xs: jnp.stack(xs).reshape(
+            P, L, *xs[0].shape), *[b for row in batches for b in row])

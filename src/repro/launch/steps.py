@@ -369,25 +369,30 @@ def make_train_step(model: Model, gfl: GFLConfig, mesh,
                 client_batch, w, a = xs
             else:
                 client_batch, w, a = xs, None, None
-            (loss, aux), grads = jax.value_and_grad(model.loss, has_aux=True)(
-                w_p, client_batch, remat_policy=remat_policy)
-            if w is not None:
-                grads = jax.tree.map(
-                    lambda g: g * w.astype(g.dtype), grads)
-            if gfl.grad_bound > 0:
-                grads, _ = clip_by_global_norm(grads, gfl.grad_bound)
-            if a is None:
-                acc = jax.tree.map(
-                    lambda c, g: c + g.astype(acc_dtype), acc, grads)
-            else:
-                acc = jax.tree.map(
-                    lambda c, g: c + g.astype(acc_dtype) * a.astype(acc_dtype),
-                    acc, grads)
-                loss = loss * a
+            with jax.named_scope("gfl.client_grads"):
+                (loss, aux), grads = jax.value_and_grad(
+                    model.loss, has_aux=True)(
+                        w_p, client_batch, remat_policy=remat_policy)
+            with jax.named_scope("gfl.clip"):
+                if w is not None:
+                    grads = jax.tree.map(
+                        lambda g: g * w.astype(g.dtype), grads)
+                if gfl.grad_bound > 0:
+                    grads, _ = clip_by_global_norm(grads, gfl.grad_bound)
+            with jax.named_scope("gfl.client_mean"):
+                if a is None:
+                    acc = jax.tree.map(
+                        lambda c, g: c + g.astype(acc_dtype), acc, grads)
+                else:
+                    acc = jax.tree.map(
+                        lambda c, g: (c + g.astype(acc_dtype)
+                                      * a.astype(acc_dtype)), acc, grads)
+                    loss = loss * a
             return acc, loss
 
-        zeros = jax.tree.map(
-            lambda p: jnp.zeros(p.shape, acc_dtype), w_p)
+        with jax.named_scope("gfl.client_mean"):
+            zeros = jax.tree.map(
+                lambda p: jnp.zeros(p.shape, acc_dtype), w_p)
         L = jax.tree_util.tree_leaves(batch_p)[0].shape[0]
         if scaled:
             a = jnp.ones((L,)) if alive_p is None else alive_p
@@ -396,12 +401,14 @@ def make_train_step(model: Model, gfl: GFLConfig, mesh,
         else:
             xs = batch_p
         acc, losses = jax.lax.scan(body, zeros, xs)
-        if alive_p is None:
-            mean_g = jax.tree.map(lambda c: (c / L).astype(jnp.float32), acc)
-            return mean_g, losses.mean()
-        n = jnp.maximum(alive_p.sum(), 1.0).astype(acc_dtype)
-        mean_g = jax.tree.map(lambda c: (c / n).astype(jnp.float32), acc)
-        return mean_g, losses.sum() / n.astype(losses.dtype)
+        with jax.named_scope("gfl.client_mean"):
+            if alive_p is None:
+                mean_g = jax.tree.map(
+                    lambda c: (c / L).astype(jnp.float32), acc)
+                return mean_g, losses.mean()
+            n = jnp.maximum(alive_p.sum(), 1.0).astype(acc_dtype)
+            mean_g = jax.tree.map(lambda c: (c / n).astype(jnp.float32), acc)
+            return mean_g, losses.sum() / n.astype(losses.dtype)
 
     def client_parallel_grads(params, batch, alive=None, weights=None):
         """Small-model mode (§Perf hillclimb 3): ALL (server, client) grads
@@ -417,42 +424,47 @@ def make_train_step(model: Model, gfl: GFLConfig, mesh,
                 w_p, client_batch, remat_policy=remat_policy)
             return grads, loss
 
-        grads, losses = jax.vmap(lambda w_p, batch_p: jax.vmap(
-            lambda cb: one_client(w_p, cb))(batch_p))(params, batch)
-        # pin [P, L, ...] grads: P -> data axes, L -> model axis
-        grads = jax.lax.with_sharding_constraint(
-            grads, jax.tree.map(
-                lambda g: NamedSharding(mesh, P(da, "model")), grads))
-        if weights is not None:
-            # cohort weights scale BEFORE the per-client clip (sensitivity
-            # stays inside grad_bound — same ordering as client_mean_grads)
-            wf = weights.astype(jnp.float32)
-            grads = jax.tree.map(
-                lambda g: g * wf.reshape(wf.shape + (1,) * (g.ndim - 2)
-                                         ).astype(g.dtype), grads)
-        if gfl.grad_bound > 0:
-            # per-(server, client) global-norm clip over the param tree
-            sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)),
-                             axis=tuple(range(2, g.ndim)))
-                     for g in jax.tree.leaves(grads))          # [P, L]
-            coef = jnp.minimum(1.0, gfl.grad_bound
-                               / jnp.maximum(jnp.sqrt(sq), 1e-12))
-            grads = jax.tree.map(
-                lambda g: (g * coef.reshape(coef.shape + (1,) * (g.ndim - 2))
-                           .astype(g.dtype)), grads)
-        if alive is None and weights is None:
+        with jax.named_scope("gfl.client_grads"):
+            grads, losses = jax.vmap(lambda w_p, batch_p: jax.vmap(
+                lambda cb: one_client(w_p, cb))(batch_p))(params, batch)
+            # pin [P, L, ...] grads: P -> data axes, L -> model axis
+            grads = jax.lax.with_sharding_constraint(
+                grads, jax.tree.map(
+                    lambda g: NamedSharding(mesh, P(da, "model")), grads))
+        with jax.named_scope("gfl.clip"):
+            if weights is not None:
+                # cohort weights scale BEFORE the per-client clip
+                # (sensitivity stays inside grad_bound — same ordering as
+                # client_mean_grads)
+                wf = weights.astype(jnp.float32)
+                grads = jax.tree.map(
+                    lambda g: g * wf.reshape(wf.shape + (1,) * (g.ndim - 2)
+                                             ).astype(g.dtype), grads)
+            if gfl.grad_bound > 0:
+                # per-(server, client) global-norm clip over the param tree
+                sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)),
+                                 axis=tuple(range(2, g.ndim)))
+                         for g in jax.tree.leaves(grads))          # [P, L]
+                coef = jnp.minimum(1.0, gfl.grad_bound
+                                   / jnp.maximum(jnp.sqrt(sq), 1e-12))
+                grads = jax.tree.map(
+                    lambda g: (g * coef.reshape(
+                        coef.shape + (1,) * (g.ndim - 2)).astype(g.dtype)),
+                    grads)
+        with jax.named_scope("gfl.client_mean"):
+            if alive is None and weights is None:
+                mean_g = jax.tree.map(
+                    lambda g: jnp.mean(g.astype(jnp.float32), axis=1), grads)
+                return mean_g, losses.mean(axis=1)
+            a = (jnp.ones(losses.shape, jnp.float32) if alive is None
+                 else alive.astype(jnp.float32))                  # [P, L]
+            n = jnp.maximum(a.sum(axis=1), 1.0)                   # [P]
             mean_g = jax.tree.map(
-                lambda g: jnp.mean(g.astype(jnp.float32), axis=1), grads)
-            return mean_g, losses.mean(axis=1)
-        a = (jnp.ones(losses.shape, jnp.float32) if alive is None
-             else alive.astype(jnp.float32))                  # [P, L]
-        n = jnp.maximum(a.sum(axis=1), 1.0)                   # [P]
-        mean_g = jax.tree.map(
-            lambda g: (g.astype(jnp.float32)
-                       * a.reshape(a.shape + (1,) * (g.ndim - 2))
-                       ).sum(axis=1) / n.reshape((-1,) + (1,) * (g.ndim - 2)),
-            grads)
-        return mean_g, (losses * a).sum(axis=1) / n
+                lambda g: (g.astype(jnp.float32)
+                           * a.reshape(a.shape + (1,) * (g.ndim - 2))
+                           ).sum(axis=1)
+                / n.reshape((-1,) + (1,) * (g.ndim - 2)), grads)
+            return mean_g, (losses * a).sum(axis=1) / n
 
     mech = mechanism_for(gfl)
     profile = mech.noise_profile()
@@ -490,10 +502,11 @@ def make_train_step(model: Model, gfl: GFLConfig, mesh,
         else:
             mean_g, loss = jax.vmap(client_mean_grads)(state.params, batch,
                                                        alive, weights)
-        psi = jax.tree.map(
-            lambda w, g: (w.astype(jnp.float32)
-                          - gfl.mu * g).astype(w.dtype),
-            state.params, mean_g)
+        with jax.named_scope("gfl.update"):
+            psi = jax.tree.map(
+                lambda w, g: (w.astype(jnp.float32)
+                              - gfl.mu * g).astype(w.dtype),
+                state.params, mean_g)
 
         # client-level residual noise (mechanisms whose masks cancel
         # exactly return None; iid returns the variance-equivalent draw —
@@ -504,42 +517,47 @@ def make_train_step(model: Model, gfl: GFLConfig, mesh,
             L = jax.tree_util.tree_leaves(batch)[0].shape[1]
             L_eff = (L if alive is None
                      else jnp.maximum(alive.sum(axis=1), 1.0))
-            cg = mech.client_noise_tree(k_client, psi, L_eff, ctx)
-            if cg is not None:
-                psi = jax.tree.map(lambda x, n: x + n, psi, cg)
+            with jax.named_scope("gfl.privatize"):
+                cg = mech.client_noise_tree(k_client, psi, L_eff, ctx)
+                if cg is not None:
+                    psi = jax.tree.map(lambda x, n: x + n, psi, cg)
 
         # (8) with the mechanism's server-level noise
-        g = (mech.combine_noise_tree(k_noise, psi, ctx)
-             if profile.server_sigma > 0 else None)
+        with jax.named_scope("gfl.privatize"):
+            g = (mech.combine_noise_tree(k_noise, psi, ctx)
+                 if profile.server_sigma > 0 else None)
         cancel = profile.server_cancels_exactly
 
-        if gfl.combine_impl == "dense":
-            # whole-run kernel switch: the cancelling noise structure maps
-            # onto the fused Pallas combine (docs/kernels.md); iid (non-
-            # cancelling) noise keeps the einsum's [P, P, D] edge draws
-            if gfl.use_kernels and (g is None or cancel):
-                new_params = _kernel_dense_combine(A_rt, psi, g)
-            else:
-                new_params = _dense_combine(A_rt, psi, g, cancel=cancel)
-        else:
-            maker = (_make_sparse_combine if gfl.combine_impl == "sparse"
-                     else _make_shardmap_combine)
-            combine = maker(mesh, cfg, gfl, state.params)
-            if g is not None:
-                # the rotating buffer carries (psi_m + g_m) exactly as the
-                # wire protocol does; cancelling mechanisms subtract their
-                # own g_p afterwards (eq. 24)
-                noisy = jax.tree.map(lambda x, n: x + n, psi, g)
-                mixed = combine(noisy, A_rt)
-                if cancel:
-                    new_params = jax.tree.map(
-                        lambda m, n: (m.astype(jnp.float32)
-                                      - n.astype(jnp.float32)).astype(m.dtype),
-                        mixed, g)
+        with jax.named_scope("gfl.combine"):
+            if gfl.combine_impl == "dense":
+                # whole-run kernel switch: the cancelling noise structure
+                # maps onto the fused Pallas combine (docs/kernels.md); iid
+                # (non-cancelling) noise keeps the einsum's [P, P, D] edge
+                # draws
+                if gfl.use_kernels and (g is None or cancel):
+                    new_params = _kernel_dense_combine(A_rt, psi, g)
                 else:
-                    new_params = mixed
+                    new_params = _dense_combine(A_rt, psi, g, cancel=cancel)
             else:
-                new_params = combine(psi, A_rt)
+                maker = (_make_sparse_combine if gfl.combine_impl == "sparse"
+                         else _make_shardmap_combine)
+                combine = maker(mesh, cfg, gfl, state.params)
+                if g is not None:
+                    # the rotating buffer carries (psi_m + g_m) exactly as
+                    # the wire protocol does; cancelling mechanisms subtract
+                    # their own g_p afterwards (eq. 24)
+                    noisy = jax.tree.map(lambda x, n: x + n, psi, g)
+                    mixed = combine(noisy, A_rt)
+                    if cancel:
+                        new_params = jax.tree.map(
+                            lambda m, n: (m.astype(jnp.float32)
+                                          - n.astype(jnp.float32)
+                                          ).astype(m.dtype),
+                            mixed, g)
+                    else:
+                        new_params = mixed
+                else:
+                    new_params = combine(psi, A_rt)
 
         metrics = {"loss": loss.mean(), "step": state.step}
         # read-only telemetry tap: the norm reductions are only traced in

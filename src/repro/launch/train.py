@@ -185,66 +185,69 @@ def main(argv=None):
         # cohort selection stream stays decoupled from the model-init seed
         sel_key = rng_key(1234)
         for i in range(args.steps):
-            ids = weights = None
-            q_round = None
-            if scheduler is not None:
-                sel = scheduler.select(jax.random.fold_in(sel_key, i), i)
-                ids, weights, q_round = sel.client_idx, sel.weights, sel.q
-            if async_drv is not None:
-                aw, flushed, q_srv = async_drv.step(i, ids)
-                weights = aw if weights is None else weights * aw
-            batch = federated_token_batches(
-                stream, seed=0, step=i, P=Pn, L=args.clients,
-                per_client=args.per_client, seq_len=args.seq,
-                client_ids=ids)
-            if process is not None:
-                real = process.realize(i)
-                alive = (process.client_alive(i, args.clients)
-                         if process.fault.client_dropout > 0 else None)
-                state, metrics = step(state, batch, real.A, alive,
-                                      cohort_weights=weights)
-                if real.gap != 0.0 and i % max(args.steps // 10, 1) == 0:
-                    print(f"  round {i}: spectral gap {real.gap:.3f}")
-            else:
-                state, metrics = step(state, batch, cohort_weights=weights)
-            losses.append(metrics["loss"])
-            if i == 0:   # compile + one step; the rest is steady state
-                jax.block_until_ready(state)
-                t1 = time.time()
-                first_step_s = t1 - t0
-            # one ledger release per protocol round, charged at THIS
-            # round's realized rate (a running mean would under-report the
-            # spend whenever q varies round to round — f(q) is convex-ish
-            # increasing, so per-release rates must be recorded as drawn).
-            # Under --async a server only releases when its buffer fills:
-            # its own ledger advances on its own cadence.
-            if async_acc is not None:
-                async_acc.record_round(flushed, q_srv)
-                eps = async_acc.epsilon()
-            else:
-                eps = acc.advance(1, q=q_round)
-            if telemetry_active():   # the loss sync is on-path only
-                rec = {"step": i, "loss": float(metrics["loss"]),
-                       "seconds": time.time() - t0}
+            # one profiler step per protocol round: a trace recorded around
+            # the loop groups each round's host spans and device ops
+            with jax.profiler.StepTraceAnnotation("gfl.round", step_num=i):
+                ids = weights = None
+                q_round = None
+                if scheduler is not None:
+                    sel = scheduler.select(jax.random.fold_in(sel_key, i), i)
+                    ids, weights, q_round = sel.client_idx, sel.weights, sel.q
+                if async_drv is not None:
+                    aw, flushed, q_srv = async_drv.step(i, ids)
+                    weights = aw if weights is None else weights * aw
+                batch = federated_token_batches(
+                    stream, seed=0, step=i, P=Pn, L=args.clients,
+                    per_client=args.per_client, seq_len=args.seq,
+                    client_ids=ids)
                 if process is not None:
-                    rec["gap"] = process.realize(i).gap
-                emit("mesh", rec)
-                if "update_norm" in metrics:
-                    emit("step", {
-                        "step": i + 1,
-                        "update_norm": float(metrics["update_norm"]),
-                        "param_norm": float(metrics["param_norm"])})
-            if i % max(args.steps // 10, 1) == 0 or i == args.steps - 1:
-                amp = (f" eps_amp {acc.amplified_epsilon():.2f} "
-                       f"(q~{scheduler.realized_q:.3g})"
-                       if scheduler is not None and async_acc is None
-                       else "")
+                    real = process.realize(i)
+                    alive = (process.client_alive(i, args.clients)
+                             if process.fault.client_dropout > 0 else None)
+                    state, metrics = step(state, batch, real.A, alive,
+                                          cohort_weights=weights)
+                    if real.gap != 0.0 and i % max(args.steps // 10, 1) == 0:
+                        print(f"  round {i}: spectral gap {real.gap:.3f}")
+                else:
+                    state, metrics = step(state, batch, cohort_weights=weights)
+                losses.append(metrics["loss"])
+                if i == 0:   # compile + one step; the rest is steady state
+                    jax.block_until_ready(state)
+                    t1 = time.time()
+                    first_step_s = t1 - t0
+                # one ledger release per protocol round, charged at THIS
+                # round's realized rate (a running mean would under-report the
+                # spend whenever q varies round to round — f(q) is convex-ish
+                # increasing, so per-release rates must be recorded as drawn).
+                # Under --async a server only releases when its buffer fills:
+                # its own ledger advances on its own cadence.
                 if async_acc is not None:
-                    rel = async_acc.releases
-                    amp = (f" eps_amp {async_acc.amplified_epsilon():.2f} "
-                           f"rel {min(rel)}-{max(rel)}")
-                print(f"step {i:4d} loss {float(metrics['loss']):.4f} "
-                      f"eps {eps:.1f}{amp} ({time.time()-t0:.0f}s)")
+                    async_acc.record_round(flushed, q_srv)
+                    eps = async_acc.epsilon()
+                else:
+                    eps = acc.advance(1, q=q_round)
+                if telemetry_active():   # the loss sync is on-path only
+                    rec = {"step": i, "loss": float(metrics["loss"]),
+                           "seconds": time.time() - t0}
+                    if process is not None:
+                        rec["gap"] = process.realize(i).gap
+                    emit("mesh", rec)
+                    if "update_norm" in metrics:
+                        emit("step", {
+                            "step": i + 1,
+                            "update_norm": float(metrics["update_norm"]),
+                            "param_norm": float(metrics["param_norm"])})
+                if i % max(args.steps // 10, 1) == 0 or i == args.steps - 1:
+                    amp = (f" eps_amp {acc.amplified_epsilon():.2f} "
+                           f"(q~{scheduler.realized_q:.3g})"
+                           if scheduler is not None and async_acc is None
+                           else "")
+                    if async_acc is not None:
+                        rel = async_acc.releases
+                        amp = (f" eps_amp {async_acc.amplified_epsilon():.2f} "
+                               f"rel {min(rel)}-{max(rel)}")
+                    print(f"step {i:4d} loss {float(metrics['loss']):.4f} "
+                          f"eps {eps:.1f}{amp} ({time.time()-t0:.0f}s)")
         jax.block_until_ready(state)
         step_s = ((time.time() - t1) / (args.steps - 1)
                   if args.steps > 1 else None)

@@ -8,11 +8,13 @@ wraps a phase (the same names ``trace_span`` uses — with
 ``profile`` stream record attributing the phase's wall clock:
 
 ``compile_s``    jaxpr tracing + MLIR lowering + XLA backend compile
-                 seconds inside the phase, measured via the
+                 seconds inside the phase (a load from the persistent
+                 compile cache included), measured via the
                  ``jax.monitoring`` duration events — so a *silent
                  recompile* (shape drift, weak-type flapping, cache
                  key bugs) shows up as nonzero ``compile_s`` +
-                 ``retraces``/``compiles`` counts long after warmup;
+                 ``retraces``/``lowerings``/``compiles``/``cache_loads``
+                 counts long after warmup;
 ``callback_s``   host seconds spent inside telemetry ``io_callback``
                  flushes (``TelemetrySession.callback_seconds``) — the
                  live cost of observation itself;
@@ -35,28 +37,35 @@ from contextlib import contextmanager, nullcontext
 from typing import Dict, Optional
 
 # jax.monitoring duration events that constitute "compile" time.  The
-# mapped name is the counter a firing increments (None = seconds only).
-_COMPILE_EVENTS: Dict[str, Optional[str]] = {
+# mapped name is the counter a firing increments.  JAX times a load from
+# the persistent compile cache as a backend compile, so ``compiles`` is
+# the backend count less the cache loads.
+_COMPILE_EVENTS: Dict[str, str] = {
     "/jax/core/compile/jaxpr_trace_duration": "retraces",
-    "/jax/core/compile/jaxpr_to_mlir_module_duration": None,
-    "/jax/core/compile/backend_compile_duration": "compiles",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lowerings",
+    "/jax/core/compile/backend_compile_duration": "backend",
 }
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
 
 # process-lifetime accumulators; phases snapshot + diff them
-_COUNTERS = {"compile_s": 0.0, "retraces": 0, "compiles": 0}
+_COUNTERS = {"compile_s": 0.0, "retraces": 0, "lowerings": 0, "backend": 0,
+             "cache_loads": 0}
 _LISTENING = False
 
 
 def _on_event_duration(event: str, duration: float, **kwargs) -> None:
     if event in _COMPILE_EVENTS:
         _COUNTERS["compile_s"] += float(duration)
-        counter = _COMPILE_EVENTS[event]
-        if counter is not None:
-            _COUNTERS[counter] += 1
+        _COUNTERS[_COMPILE_EVENTS[event]] += 1
+
+
+def _on_event(event: str, **kwargs) -> None:
+    if event == _CACHE_HIT:
+        _COUNTERS["cache_loads"] += 1
 
 
 def ensure_listener() -> bool:
-    """Register the jax.monitoring duration listener once per process.
+    """Register the jax.monitoring listeners once per process.
     Returns False when the monitoring API is unavailable (profiler then
     reports wall/callback attribution only)."""
     global _LISTENING
@@ -65,6 +74,7 @@ def ensure_listener() -> bool:
     try:
         from jax import monitoring
         monitoring.register_event_duration_secs_listener(_on_event_duration)
+        monitoring.register_event_listener(_on_event)
     except Exception:
         return False
     _LISTENING = True
@@ -72,8 +82,14 @@ def ensure_listener() -> bool:
 
 
 def compile_counters() -> Dict[str, float]:
-    """A snapshot of the process-lifetime compile accumulators."""
-    return dict(_COUNTERS)
+    """A snapshot of the process-lifetime compile accumulators:
+    ``compile_s`` (trace + lowering + backend seconds, cache loads
+    included), ``retraces``, ``lowerings``, ``compiles`` (XLA backend
+    compiles) and ``cache_loads`` (programs loaded from the persistent
+    compile cache instead)."""
+    c = dict(_COUNTERS)
+    c["compiles"] = c.pop("backend") - c["cache_loads"]
+    return c
 
 
 def device_peak_bytes() -> Optional[int]:
@@ -119,8 +135,8 @@ def profile_phase(name: str, **args):
             "wall_s": wall, "compile_s": compile_s,
             "execute_s": max(0.0, wall - compile_s - callback_s),
             "callback_s": callback_s,
-            "retraces": int(after["retraces"] - before["retraces"]),
-            "compiles": int(after["compiles"] - before["compiles"]),
+            **{k: int(after[k] - before[k]) for k in
+               ("retraces", "lowerings", "compiles", "cache_loads")},
         }
         peak = device_peak_bytes()
         if peak is not None:

@@ -217,13 +217,18 @@ register_schema(Schema(
         Field("phase", "str", "trace_span phase name"),
         Field("wall_s", "scalar", "phase wall-clock seconds"),
         Field("compile_s", "scalar", "jaxpr trace + lowering + backend "
-                                     "compile seconds inside the phase"),
+                                     "compile seconds inside the phase "
+                                     "(cache loads included)"),
         Field("execute_s", "scalar", "wall minus compile minus callback "
                                      "(device execute + host driver)"),
         Field("callback_s", "scalar", "host seconds inside telemetry "
                                       "io_callback flushes"),
         Field("retraces", "int", "jaxpr traces started inside the phase"),
-        Field("compiles", "int", "XLA backend compiles inside the phase"),
+        Field("lowerings", "int", "MLIR lowerings inside the phase"),
+        Field("compiles", "int", "XLA backend compiles inside the phase "
+                                 "(cache loads not counted)"),
+        Field("cache_loads", "int", "programs loaded from the persistent "
+                                    "compile cache inside the phase"),
         Field("peak_bytes", "scalar", "device peak_bytes_in_use after the "
                                       "phase (absent when the backend has "
                                       "no memory_stats)"),

@@ -9,13 +9,14 @@ Perfetto (``{"traceEvents": [...]}`` format).
 Spans wrapped around *jitted* bodies measure trace/compile/autotune
 time (the body runs once per compilation) — that is the intended
 semantics: dispatch-time attribution, not per-execution device timing.
-For device-side profiling every span can also pass through to
-``jax.profiler.TraceAnnotation`` (``annotate=True`` on the tracer, or
-``REPRO_TELEMETRY_JAXPROF=1``), so spans show up in a jax profiler
-capture under the same names.
 
-With no active telemetry session ``trace_span`` is a reusable no-op
-context manager — zero allocation on the off path.
+While a ``jax.profiler`` trace is being recorded, every ``trace_span`` is
+also written into it as a ``jax.profiler.TraceAnnotation`` of the same
+name and arguments, with or without a telemetry session, so program
+spans sit on the profiler's clock beside the device's ops.
+
+With no telemetry session and no profiler trace ``trace_span`` returns a
+reusable no-op context manager — no allocation on the off path.
 """
 from __future__ import annotations
 
@@ -23,21 +24,19 @@ import json
 import os
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from pathlib import Path
 from typing import List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class SpanTracer:
     """Collects trace events; ``save`` writes Chrome trace JSON."""
 
-    def __init__(self, path=None, *, annotate: Optional[bool] = None):
+    def __init__(self, path=None):
         self.path = Path(path) if path else None
         self.events: List[dict] = []
-        if annotate is None:
-            annotate = os.environ.get(
-                "REPRO_TELEMETRY_JAXPROF", "0") not in ("", "0", "false")
-        self.annotate = annotate
         self._t0 = time.perf_counter()
         self._pid = os.getpid()
 
@@ -46,14 +45,6 @@ class SpanTracer:
 
     @contextmanager
     def span(self, name: str, **args):
-        ann = None
-        if self.annotate:
-            try:
-                import jax
-                ann = jax.profiler.TraceAnnotation(name)
-                ann.__enter__()
-            except Exception:       # profiler unavailable: spans still work
-                ann = None
         ts = self._now_us()
         try:
             yield
@@ -64,8 +55,6 @@ class SpanTracer:
                 "pid": self._pid, "tid": threading.get_ident() & 0xFFFF,
                 "args": {k: _arg(v) for k, v in args.items()},
             })
-            if ann is not None:
-                ann.__exit__(None, None, None)
 
     def instant(self, name: str, **args) -> None:
         """A zero-duration marker event."""
@@ -96,26 +85,38 @@ def _arg(value):
     return str(value)
 
 
-@contextmanager
-def _null_span():
-    yield
+_NULL_SPAN = nullcontext()
 
 
 def trace_span(name: str, **args):
-    """Span against the active session's tracer (no-op when telemetry is
-    off).  Usage: ``with trace_span("round_fold", P=P, D=D): ...``
+    """Span of a host-side phase.  Usage:
+    ``with trace_span("gfl.input", round=i): ...``
 
-    Profiling sessions (``session(profile=True)`` /
+    It goes to the active session's tracer (Chrome JSON), and, while a
+    ``jax.profiler`` trace is being recorded, into that trace as a
+    ``TraceAnnotation``.  Profiling sessions (``session(profile=True)`` /
     ``REPRO_TELEMETRY_PROFILE=1``) additionally attribute every span's
     wall time to compile/execute/callback via the ``profile`` stream
-    (:mod:`repro.telemetry.profile`)."""
+    (:mod:`repro.telemetry.profile`).  With neither a session nor a
+    profiler trace it is a no-op."""
     from repro.telemetry.stream import current_session
     sess = current_session()
     if sess is None:
-        return _null_span()
-    if sess.profile:
+        span = _NULL_SPAN
+    elif sess.profile:
         from repro.telemetry.profile import profile_phase
-        return profile_phase(name, **args)
-    if sess.tracer is None:
-        return _null_span()
-    return sess.tracer.span(name, **args)
+        span = profile_phase(name, **args)
+    elif sess.tracer is not None:
+        span = sess.tracer.span(name, **args)
+    else:
+        span = _NULL_SPAN
+    if not TraceAnnotation.is_enabled():
+        return span
+    ann = TraceAnnotation(name, **{k: _arg(v) for k, v in args.items()})
+    return ann if span is _NULL_SPAN else _both(ann, span)
+
+
+@contextmanager
+def _both(ann, span):
+    with ann, span:
+        yield

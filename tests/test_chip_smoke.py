@@ -48,8 +48,7 @@ def test_engine_phase_holds_on_cpu(capsys):
         import chip_smoke
     finally:
         sys.path.remove(REPO)
-    chip_smoke.phase_fused_round(chip_smoke.CompileClock(), P=4, K=20, L=3,
-                                 batch=4, M=300, iters=3,
+    chip_smoke.phase_fused_round(P=4, K=20, L=3, batch=4, M=300, iters=3,
                                  expect=("pallas", True))
     out = capsys.readouterr().out
     for privacy in ("none", "hybrid", "iid_dp"):

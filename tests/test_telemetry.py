@@ -249,22 +249,79 @@ def test_profile_off_by_default():
     assert sess.memory_records("profile") == []
 
 
-def test_jaxprof_env_passthrough(monkeypatch):
-    from repro.telemetry.trace import SpanTracer
-    monkeypatch.delenv("REPRO_TELEMETRY_JAXPROF", raising=False)
-    assert SpanTracer().annotate is False
-    monkeypatch.setenv("REPRO_TELEMETRY_JAXPROF", "1")
-    tracer = SpanTracer()
-    assert tracer.annotate is True
-    # annotated spans still record events (TraceAnnotation wraps cleanly
-    # even outside a profiler capture)
-    with tracer.span("annotated", k=1):
-        pass
-    assert [e["name"] for e in tracer.events] == ["annotated"]
-    # explicit annotate beats the env var
-    assert SpanTracer(annotate=False).annotate is False
-    monkeypatch.setenv("REPRO_TELEMETRY_JAXPROF", "0")
-    assert SpanTracer().annotate is False
+def test_trace_span_reaches_profiler_without_session(tmp_path):
+    """With no telemetry session, a ``trace_span`` inside a jax.profiler
+    trace is written into it as a host event of that name, with its
+    arguments, on the profiler's clock; outside any trace and session it
+    is one reusable no-op."""
+    from jax.profiler import ProfileData
+    assert not telemetry_active()
+    assert trace_span("a") is trace_span("b", k=1)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace_span("gfl.probe", round=3):
+            jax.block_until_ready(jnp.arange(8) + 1)
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    events = [e for plane in ProfileData.from_file(str(path)).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name == "gfl.probe"]
+    assert len(events) == 1
+    assert events[0].duration_ns > 0
+    assert str(dict(events[0].stats)["round"]) == "3"
+
+
+def test_profile_counts_cache_loads_apart_from_compiles(tmp_path):
+    """A program loaded from the persistent compile cache is a
+    ``cache_loads``, not a ``compiles``, in the ``profile`` stream."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    old = {k: getattr(jax.config, k) for k in keys}
+    for k, v in zip(keys, (str(tmp_path), 0, 0)):
+        jax.config.update(k, v)
+    cc.reset_cache()
+    try:
+        x = jnp.arange(37, dtype=jnp.float32)
+        with session("memory", profile=True) as sess:
+            for phase in ("cold", "warm"):
+                # a fresh closure each time: traced and lowered again, and
+                # the second time loaded from the cache the first one filled
+                f = jax.jit(lambda v: jnp.sin(v) * 3 + 0.5)
+                with trace_span(phase):
+                    jax.block_until_ready(f(x))
+        recs = {r["phase"]: r for r in sess.memory_records("profile")}
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    for r in recs.values():
+        validate_record("profile", {k: v for k, v in r.items()
+                                    if k not in ("stream", "run", "t_wall")})
+        assert r["retraces"] >= 1 and r["lowerings"] == 1
+    assert (recs["cold"]["compiles"], recs["cold"]["cache_loads"]) == (1, 0)
+    assert (recs["warm"]["compiles"], recs["warm"]["cache_loads"]) == (0, 1)
+
+
+def test_round_phases_are_named_spans(tmp_path):
+    """The input build and the accountant run in ``gfl.input`` and
+    ``gfl.accountant`` spans that carry the round they belong to."""
+    from repro.core.privacy.mechanism import mechanism_for
+    from repro.data import TokenStream, federated_token_batches
+    trace = tmp_path / "t.trace.json"
+    acc = mechanism_for(GFLConfig(privacy="hybrid")).accountant()
+    acc.advance(4)
+    with session("memory", trace_path=trace):
+        federated_token_batches(TokenStream(vocab=64), seed=0, step=4, P=1,
+                                L=2, per_client=1, seq_len=8)
+        acc.advance(1)
+    events = {e["name"]: e for e in
+              json.loads(trace.read_text())["traceEvents"]}
+    assert events["gfl.input"]["args"] == {"round": 4}
+    assert events["gfl.accountant"]["args"] == {"round": 4}
 
 
 # ------------------------------------------------------------------ sketch
