@@ -350,6 +350,9 @@ def make_train_step(model: Model, gfl: GFLConfig, mesh,
             "use combine_impl='rotate' (or 'dense') with fault specs")
 
     acc_dtype = jnp.dtype(gfl.grad_acc_dtype)
+    # GSPMD cannot partition the fused attention kernel's Mosaic call, so
+    # the model may take it only when the whole step runs on one device
+    one_device = mesh.size == 1
 
     def client_mean_grads(w_p, batch_p, alive_p=None, weights_p=None):
         """(6)+(7): scan over L clients; per-client clip to B; mean.
@@ -372,7 +375,8 @@ def make_train_step(model: Model, gfl: GFLConfig, mesh,
             with jax.named_scope("gfl.client_grads"):
                 (loss, aux), grads = jax.value_and_grad(
                     model.loss, has_aux=True)(
-                        w_p, client_batch, remat_policy=remat_policy)
+                        w_p, client_batch, remat_policy=remat_policy,
+                        one_device=one_device)
             with jax.named_scope("gfl.clip"):
                 if w is not None:
                     grads = jax.tree.map(
@@ -421,7 +425,8 @@ def make_train_step(model: Model, gfl: GFLConfig, mesh,
 
         def one_client(w_p, client_batch):
             (loss, _), grads = jax.value_and_grad(model.loss, has_aux=True)(
-                w_p, client_batch, remat_policy=remat_policy)
+                w_p, client_batch, remat_policy=remat_policy,
+                one_device=one_device)
             return grads, loss
 
         with jax.named_scope("gfl.client_grads"):

@@ -1,10 +1,25 @@
 """Attention variants: GQA (optional sliding window) and MLA (DeepSeek/MiniCPM).
 
-Prefill uses query-chunked attention so the [S, S] score matrix is never
-materialized (a 32k prefill would otherwise need O(S^2) HBM).  Sliding-window
-archs additionally restrict the key slice per chunk, making prefill
-sub-quadratic and allowing a ring-buffer KV cache of just `window` slots —
-this is what makes `long_500k` feasible for SWA archs.
+Causal GQA self-attention (training and prefill) takes one of two paths:
+
+- the fused kernel, ``jax.experimental.pallas.ops.tpu.flash_attention``
+  with its fused dq and dk/dv backward kernels: the running max, sum and
+  logsumexp stay in VMEM in float32, key blocks entirely above the
+  diagonal are skipped, and no [S, S] tensor reaches HBM.  It engages
+  only where the call shows it can: a TPU backend, causal self-attention,
+  no sliding window shorter than S, S a multiple of ``FLASH_BLOCK``, and
+  operands the caller vouches live on one device (``one_device``; a
+  Mosaic call cannot be partitioned by GSPMD);
+- everywhere else, query-chunked attention (``_chunked_causal_attention``):
+  each chunk's float32 scores against its key slice are materialized, so
+  the [S, S] matrix is never whole (a 32k prefill would otherwise need
+  O(S^2) HBM).  Sliding-window archs additionally restrict the key slice
+  per chunk, making prefill sub-quadratic and allowing a ring-buffer KV
+  cache of just `window` slots — this is what makes `long_500k` feasible
+  for SWA archs.
+
+Each ``gqa_forward`` trace emits one ``kernel`` telemetry record
+(``op="flash_attention"``) saying which path it took and why.
 """
 from __future__ import annotations
 
@@ -15,6 +30,11 @@ from repro.configs.base import MLAConfig, ModelConfig
 from repro.models.layers import apply_rope, he_init
 
 NEG_INF = -1e30
+
+# The fused kernel's q and k block, forward and both backward kernels.  On
+# a TPU v5e at S=2048, Dh=64 a forward and backward took 2.16 ms at 512,
+# 2.29 at 1024, 2.82 at 256 and 4.70 at 128 (PERF.md §6).
+FLASH_BLOCK = 512
 
 
 # ---------------------------------------------------------------------------
@@ -84,13 +104,59 @@ def _chunked_causal_attention(q, k, v, *, window: int, chunk: int):
     return jnp.moveaxis(outs, 0, 1).reshape(B, S, KV, G, Dh)
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def flash_fallback_reason(S: int, *, causal: bool, cross: bool, window: int,
+                          one_device: bool) -> str:
+    """Why the fused kernel does not take this attention; '' when it does."""
+    if cross:
+        return "cross_attention"
+    if not causal:
+        return "not_causal"
+    if window and window < S:
+        return "sliding_window"
+    if S % FLASH_BLOCK:
+        return "seq_not_block_multiple"
+    if not one_device:
+        return "not_one_device"
+    if not _on_tpu():
+        return f"backend_{jax.default_backend()}"
+    return ""
+
+
+def _flash_causal_attention(q, k, v, *, block: int):
+    """q: [B,S,KV,G,Dh]; k,v: [B,S,KV,Dh]. Causal attention by the fused
+    Pallas TPU kernel, K and V repeated over each KV head's G query heads
+    (head h = kv * G + g, as ``q``'s reshape groups them)."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+    B, S, KV, G, Dh = q.shape
+
+    def heads(x):                                    # [B,S,H,Dh]<->[B,H,S,Dh]
+        return jnp.swapaxes(x, 1, 2)
+
+    sizes = fa.BlockSizes(
+        block_q=block, block_k_major=block, block_k=block, block_b=1,
+        block_q_major_dkv=block, block_k_major_dkv=block, block_k_dkv=block,
+        block_q_dkv=block, block_k_major_dq=block, block_k_dq=block,
+        block_q_dq=block)
+    out = fa.flash_attention(
+        heads(q.reshape(B, S, KV * G, Dh)),
+        heads(jnp.repeat(k, G, axis=2)), heads(jnp.repeat(v, G, axis=2)),
+        causal=True, sm_scale=1.0 / float(Dh) ** 0.5, block_sizes=sizes)
+    return heads(out).reshape(B, S, KV, G, Dh)
+
+
 def gqa_forward(params, x, positions, cfg: ModelConfig, *, chunk: int = 1024,
                 use_rope: bool = True, causal: bool = True,
-                kv_src: jax.Array | None = None):
+                kv_src: jax.Array | None = None, one_device: bool = False):
     """Training/prefill attention. x: [B,S,D] -> [B,S,D].
 
     kv_src: optional separate K/V source sequence (cross-attention); implies
-    non-causal full attention over kv_src.
+    non-causal full attention over kv_src.  one_device: the caller's
+    operands live on one device, so the fused kernel may take causal
+    self-attention (see the module docstring).
     """
     h, kv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     B, S, _ = x.shape
@@ -103,7 +169,15 @@ def gqa_forward(params, x, positions, cfg: ModelConfig, *, chunk: int = 1024,
         k = apply_rope(k, jnp.broadcast_to(jnp.arange(src.shape[1]),
                                            src.shape[:2]), cfg.rope_theta)
     q = q.reshape(B, S, kv, h // kv, dh)
-    if causal and kv_src is None:
+    reason = flash_fallback_reason(
+        S, causal=causal, cross=kv_src is not None,
+        window=cfg.sliding_window, one_device=one_device)
+    from repro.kernels.ops import _emit_kernel   # Pallas: import on use
+    _emit_kernel(op="flash_attention", engaged=int(not reason),
+                 reason=reason, seq_len=S, heads=h, head_dim=dh)
+    if not reason:
+        out = _flash_causal_attention(q, k, v, block=FLASH_BLOCK)
+    elif causal and kv_src is None:
         out = _chunked_causal_attention(q, k, v, window=cfg.sliding_window,
                                         chunk=chunk)
     else:

@@ -57,17 +57,17 @@ def _attn_op(bp, h, positions, cfg, **kw):
     return attn.gqa_forward(bp["attn"], h, positions, cfg, **kw)
 
 
-def _dense_block(bp, x, positions, cfg: ModelConfig):
+def _dense_block(bp, x, positions, cfg: ModelConfig, one_device=False):
     h = nn.rms_norm(bp["ln1"], x, cfg.norm_eps)
-    x = x + _attn_op(bp, h, positions, cfg)
+    x = x + _attn_op(bp, h, positions, cfg, one_device=one_device)
     h = nn.rms_norm(bp["ln2"], x, cfg.norm_eps)
     x = x + nn.swiglu(bp["mlp"], h)
     return x, jnp.zeros((), jnp.float32)
 
 
-def _moe_block(bp, x, positions, cfg: ModelConfig):
+def _moe_block(bp, x, positions, cfg: ModelConfig, one_device=False):
     h = nn.rms_norm(bp["ln1"], x, cfg.norm_eps)
-    x = x + _attn_op(bp, h, positions, cfg)
+    x = x + _attn_op(bp, h, positions, cfg, one_device=one_device)
     h = nn.rms_norm(bp["ln2"], x, cfg.norm_eps)
     out, aux = moe_lib.moe_forward(bp["moe"], h, cfg)
     return x + out, aux
@@ -287,8 +287,11 @@ class Model:
         return jax.checkpoint(fn, policy=pol)
 
     def forward(self, params, batch, *, remat: bool = True,
-                remat_policy: str | None = None):
-        """Full-sequence logits [B,S,V] (train / prefill compute path)."""
+                remat_policy: str | None = None, one_device: bool = False):
+        """Full-sequence logits [B,S,V] (train / prefill compute path).
+
+        one_device: the caller's operands live on one device, so causal
+        self-attention may take the fused kernel (models/attention.py)."""
         cfg = self.cfg
         x, _ = self._embed_inputs(params, batch)
         B, S, _ = x.shape
@@ -301,7 +304,8 @@ class Model:
             def dec_body(x, bp):
                 h = nn.layer_norm(bp["ln1"], x, cfg.norm_eps)
                 x = x + attn.gqa_forward(bp["attn"], h, positions, cfg,
-                                         use_rope=False, causal=True)
+                                         use_rope=False, causal=True,
+                                         one_device=one_device)
                 h = nn.layer_norm(bp["ln_x"], x, cfg.norm_eps)
                 x = x + attn.gqa_forward(bp["xattn"], h, None, cfg,
                                          use_rope=False, causal=False,
@@ -338,7 +342,8 @@ class Model:
 
             def shared_block(x):
                 h = nn.rms_norm(shared["ln"], x, cfg.norm_eps)
-                x = x + attn.gqa_forward(shared["attn"], h, positions, cfg)
+                x = x + attn.gqa_forward(shared["attn"], h, positions, cfg,
+                                         one_device=one_device)
                 h = nn.rms_norm(shared["ln2"], x, cfg.norm_eps)
                 return x + nn.swiglu(shared["mlp"], h)
 
@@ -353,7 +358,7 @@ class Model:
                 d_ff = cfg.moe.first_dense_d_ff or cfg.d_ff
 
                 def dbody(x, bp):
-                    x, _ = _dense_block(bp, x, positions, cfg)
+                    x, _ = _dense_block(bp, x, positions, cfg, one_device)
                     return x, None
 
                 dbody = self._ckpt(dbody, remat, remat_policy)
@@ -363,7 +368,7 @@ class Model:
 
             def body(carry, bp):
                 x, aux = carry
-                x, a = block(bp, x, positions, cfg)
+                x, a = block(bp, x, positions, cfg, one_device)
                 return (x, aux + a), None
 
             body = self._ckpt(body, remat, remat_policy)
@@ -382,12 +387,13 @@ class Model:
     # --------------------------------------------------------------- loss --
 
     def loss(self, params, batch, *, remat: bool = True,
-             remat_policy: str | None = None):
+             remat_policy: str | None = None, one_device: bool = False):
         """Next-token CE; returns (loss, aux_dict)."""
         cfg = self.cfg
         self._last_aux = jnp.zeros((), jnp.float32)
         logits = self.forward(params, batch, remat=remat,
-                              remat_policy=remat_policy)
+                              remat_policy=remat_policy,
+                              one_device=one_device)
         labels = batch["labels"]
         if cfg.family == "vlm":
             # logits cover [img; text]; labels only cover text

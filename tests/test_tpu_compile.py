@@ -94,3 +94,26 @@ def test_graph_combine_compiles_for_v5e(one_chip, no_cache, noisy):
 
     compiled = jax.jit(combine).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
+def test_flash_attention_compiles_for_v5e(one_chip, no_cache, grad):
+    """The fused causal attention at smollm-135m's widths and the benchmark
+    cell's rows (B=2, S=2048, 9 heads over 3 KV heads, Dh=64), forward and
+    with its fused backward kernels, at the model's block."""
+    from repro.models import attention as attn
+    B, S, KV, G, Dh = 2, 2048, 3, 3, 64
+    args = [_sds((B, S, KV, G, Dh), jnp.bfloat16, one_chip),
+            _sds((B, S, KV, Dh), jnp.bfloat16, one_chip),
+            _sds((B, S, KV, Dh), jnp.bfloat16, one_chip)]
+
+    def fwd(q, k, v):
+        return attn._flash_causal_attention(q, k, v, block=attn.FLASH_BLOCK)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(fwd_bwd if grad else fwd).lower(*args).compile()
+    # forward: one kernel; backward: the forward with residuals, dk/dv, dq
+    assert compiled.as_text().count("tpu_custom_call") >= (3 if grad else 1)
