@@ -38,17 +38,42 @@ INPUT_SHAPES = {
 
 @dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int = 0          # routed experts
+    num_experts: int = 0          # routed experts (the router's outputs)
     num_shared_experts: int = 0   # always-on experts (DeepSeek style)
     top_k: int = 2
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # global / row dispatch only
     expert_d_ff: int = 0          # d_ff of each routed expert
-    router_aux_coef: float = 0.01
+    router_aux_coef: float = 0.01  # coefficient of the balance loss
+    balance: str = "switch"       # switch: over the whole token pool;
+                                  # sequence: DeepSeek-V2's per-sequence
+                                  # expert-level loss
+    norm_topk: bool = True        # renormalise the top-k gates to sum 1
     first_dense_layers: int = 0   # leading layers that stay dense (DeepSeek)
     first_dense_d_ff: int = 0
     dispatch: str = "global"      # global: capacity over all tokens (t5x);
                                   # row: per-batch-row dispatch — scatter
-                                  # stays local to the data shard (§Perf)
+                                  # stays local to the data shard (§Perf);
+                                  # dropless: no capacity, held experts
+                                  # through a grouped matmul
+    experts_held: int = 0         # routed experts whose weights live here
+                                  # (expert parallelism; dropless only);
+                                  # 0 -> all of them
+    expert_offset: int = 0        # id of the first held expert
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (Peng et al. 2023), as DeepSeek-V2 states it."""
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclass(frozen=True)
@@ -94,6 +119,7 @@ class ModelConfig:
     head_dim: int = 0             # 0 -> d_model // num_heads
     max_seq_len: int = 1 << 20
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnConfig] = None   # None -> plain rope
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     sliding_window: int = 0       # 0 -> full attention
@@ -143,6 +169,8 @@ class ModelConfig:
             moe = dataclasses.replace(
                 self.moe,
                 num_experts=min(self.moe.num_experts, 4),
+                experts_held=min(self.moe.experts_held, 4),
+                expert_offset=0,
                 num_shared_experts=min(self.moe.num_shared_experts, 1),
                 top_k=min(self.moe.top_k, 2),
                 expert_d_ff=min(self.moe.expert_d_ff or 128, 128),

@@ -60,6 +60,16 @@ class TrainState(NamedTuple):
     key: jax.Array
 
 
+#: device counters a model reports in its loss's aux dict, summed over each
+#: server's clients into the step's metrics ([P, ...]); read after a run,
+#: never inside it
+STEP_COUNTERS = ("expert_tokens",)
+
+
+def _step_counters(aux: dict) -> dict:
+    return {k: aux[k] for k in STEP_COUNTERS if k in aux}
+
+
 # ---------------------------------------------------------------------------
 # combine implementations
 # ---------------------------------------------------------------------------
@@ -377,6 +387,7 @@ def make_train_step(model: Model, gfl: GFLConfig, mesh,
                     model.loss, has_aux=True)(
                         w_p, client_batch, remat_policy=remat_policy,
                         one_device=one_device)
+            counters = _step_counters(aux)
             with jax.named_scope("gfl.clip"):
                 if w is not None:
                     grads = jax.tree.map(
@@ -392,7 +403,7 @@ def make_train_step(model: Model, gfl: GFLConfig, mesh,
                         lambda c, g: (c + g.astype(acc_dtype)
                                       * a.astype(acc_dtype)), acc, grads)
                     loss = loss * a
-            return acc, loss
+            return acc, (loss, counters)
 
         with jax.named_scope("gfl.client_mean"):
             zeros = jax.tree.map(
@@ -404,15 +415,16 @@ def make_train_step(model: Model, gfl: GFLConfig, mesh,
             xs = (batch_p, w, a)
         else:
             xs = batch_p
-        acc, losses = jax.lax.scan(body, zeros, xs)
+        acc, (losses, counters) = jax.lax.scan(body, zeros, xs)
+        counters = jax.tree.map(lambda c: c.sum(0), counters)
         with jax.named_scope("gfl.client_mean"):
             if alive_p is None:
                 mean_g = jax.tree.map(
                     lambda c: (c / L).astype(jnp.float32), acc)
-                return mean_g, losses.mean()
+                return mean_g, losses.mean(), counters
             n = jnp.maximum(alive_p.sum(), 1.0).astype(acc_dtype)
             mean_g = jax.tree.map(lambda c: (c / n).astype(jnp.float32), acc)
-            return mean_g, losses.sum() / n.astype(losses.dtype)
+            return mean_g, losses.sum() / n.astype(losses.dtype), counters
 
     def client_parallel_grads(params, batch, alive=None, weights=None):
         """Small-model mode (§Perf hillclimb 3): ALL (server, client) grads
@@ -424,14 +436,16 @@ def make_train_step(model: Model, gfl: GFLConfig, mesh,
         da = saxes_ if len(saxes_) > 1 else saxes_[0]
 
         def one_client(w_p, client_batch):
-            (loss, _), grads = jax.value_and_grad(model.loss, has_aux=True)(
-                w_p, client_batch, remat_policy=remat_policy,
-                one_device=one_device)
-            return grads, loss
+            (loss, aux), grads = jax.value_and_grad(
+                model.loss, has_aux=True)(
+                    w_p, client_batch, remat_policy=remat_policy,
+                    one_device=one_device)
+            return grads, loss, _step_counters(aux)
 
         with jax.named_scope("gfl.client_grads"):
-            grads, losses = jax.vmap(lambda w_p, batch_p: jax.vmap(
+            grads, losses, counters = jax.vmap(lambda w_p, batch_p: jax.vmap(
                 lambda cb: one_client(w_p, cb))(batch_p))(params, batch)
+            counters = jax.tree.map(lambda c: c.sum(1), counters)
             # pin [P, L, ...] grads: P -> data axes, L -> model axis
             grads = jax.lax.with_sharding_constraint(
                 grads, jax.tree.map(
@@ -460,7 +474,7 @@ def make_train_step(model: Model, gfl: GFLConfig, mesh,
             if alive is None and weights is None:
                 mean_g = jax.tree.map(
                     lambda g: jnp.mean(g.astype(jnp.float32), axis=1), grads)
-                return mean_g, losses.mean(axis=1)
+                return mean_g, losses.mean(axis=1), counters
             a = (jnp.ones(losses.shape, jnp.float32) if alive is None
                  else alive.astype(jnp.float32))                  # [P, L]
             n = jnp.maximum(a.sum(axis=1), 1.0)                   # [P]
@@ -469,7 +483,7 @@ def make_train_step(model: Model, gfl: GFLConfig, mesh,
                            * a.reshape(a.shape + (1,) * (g.ndim - 2))
                            ).sum(axis=1)
                 / n.reshape((-1,) + (1,) * (g.ndim - 2)), grads)
-            return mean_g, (losses * a).sum(axis=1) / n
+            return mean_g, (losses * a).sum(axis=1) / n, counters
 
     mech = mechanism_for(gfl)
     profile = mech.noise_profile()
@@ -493,20 +507,21 @@ def make_train_step(model: Model, gfl: GFLConfig, mesh,
 
         # (6)+(7) per server, vmapped over the sharded server dim
         if gfl.client_parallel:
-            mean_g, loss = client_parallel_grads(state.params, batch, alive,
-                                                 weights)
+            mean_g, loss, counters = client_parallel_grads(
+                state.params, batch, alive, weights)
         elif alive is None and weights is None:
-            mean_g, loss = jax.vmap(client_mean_grads)(state.params, batch)
+            mean_g, loss, counters = jax.vmap(client_mean_grads)(
+                state.params, batch)
         elif weights is None:
-            mean_g, loss = jax.vmap(client_mean_grads)(state.params, batch,
-                                                       alive)
+            mean_g, loss, counters = jax.vmap(client_mean_grads)(
+                state.params, batch, alive)
         elif alive is None:
-            mean_g, loss = jax.vmap(
+            mean_g, loss, counters = jax.vmap(
                 lambda w_p, b_p, s_p: client_mean_grads(w_p, b_p, None, s_p)
             )(state.params, batch, weights)
         else:
-            mean_g, loss = jax.vmap(client_mean_grads)(state.params, batch,
-                                                       alive, weights)
+            mean_g, loss, counters = jax.vmap(client_mean_grads)(
+                state.params, batch, alive, weights)
         with jax.named_scope("gfl.update"):
             psi = jax.tree.map(
                 lambda w, g: (w.astype(jnp.float32)
@@ -564,7 +579,7 @@ def make_train_step(model: Model, gfl: GFLConfig, mesh,
                 else:
                     new_params = combine(psi, A_rt)
 
-        metrics = {"loss": loss.mean(), "step": state.step}
+        metrics = {"loss": loss.mean(), "step": state.step, **counters}
         # read-only telemetry tap: the norm reductions are only traced in
         # when a session is active at build time — the step closure is
         # rebuilt per make_train_step call, so the off path compiles the

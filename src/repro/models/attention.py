@@ -1,6 +1,8 @@
 """Attention variants: GQA (optional sliding window) and MLA (DeepSeek/MiniCPM).
 
-Causal GQA self-attention (training and prefill) takes one of two paths:
+Causal GQA self-attention (training and prefill) takes one of two paths
+(MLA's takes the same gate, with JAX's Pallas TPU splash kernel as its fused
+path, since its value heads are narrower than its query/key heads):
 
 - the fused kernel, ``jax.experimental.pallas.ops.tpu.flash_attention``
   with its fused dq and dk/dv backward kernels: the running max, sum and
@@ -18,8 +20,9 @@ Causal GQA self-attention (training and prefill) takes one of two paths:
   cache of just `window` slots — this is what makes `long_500k` feasible
   for SWA archs.
 
-Each ``gqa_forward`` trace emits one ``kernel`` telemetry record
-(``op="flash_attention"``) saying which path it took and why.
+Each ``gqa_forward`` and ``mla_forward`` trace emits one ``kernel``
+telemetry record (``op="flash_attention"``) saying which path it took and
+why.
 """
 from __future__ import annotations
 
@@ -27,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import MLAConfig, ModelConfig
-from repro.models.layers import apply_rope, he_init
+from repro.models.layers import apply_rope, he_init, yarn_mscale
 
 NEG_INF = -1e30
 
@@ -35,6 +38,10 @@ NEG_INF = -1e30
 # a TPU v5e at S=2048, Dh=64 a forward and backward took 2.16 ms at 512,
 # 2.29 at 1024, 2.82 at 256 and 4.70 at 128 (PERF.md §6).
 FLASH_BLOCK = 512
+# MLA's (splash): at B=2, S=4096, 16 heads of 192 / 128 on a v5e a forward
+# and backward took 9.28 ms at 1024 and 9.94 at 512; flash_attention with
+# both head dims padded to 256 took 13.74 at 512 (PERF.md §6).
+SPLASH_BLOCK = 1024
 
 
 # ---------------------------------------------------------------------------
@@ -109,15 +116,16 @@ def _on_tpu() -> bool:
 
 
 def flash_fallback_reason(S: int, *, causal: bool, cross: bool, window: int,
-                          one_device: bool) -> str:
-    """Why the fused kernel does not take this attention; '' when it does."""
+                          one_device: bool, block: int | None = None) -> str:
+    """Why the fused kernel (of q and k blocks ``block``, by default
+    ``FLASH_BLOCK``) does not take this attention; '' when it does."""
     if cross:
         return "cross_attention"
     if not causal:
         return "not_causal"
     if window and window < S:
         return "sliding_window"
-    if S % FLASH_BLOCK:
+    if S % (block or FLASH_BLOCK):
         return "seq_not_block_multiple"
     if not one_device:
         return "not_one_device"
@@ -290,7 +298,7 @@ def mla_init(key, cfg: ModelConfig, dtype):
     return p
 
 
-def _rms(x, scale, eps=1e-5):
+def _rms(x, scale, eps):
     xf = x.astype(jnp.float32)
     xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
     return (xf * scale.astype(jnp.float32)).astype(x.dtype)
@@ -302,21 +310,70 @@ def _mla_qkr(params, x, positions, cfg: ModelConfig):
     h = cfg.num_heads
     dn, dr = m.qk_nope_head_dim, m.qk_rope_head_dim
     B, S, _ = x.shape
+    eps, yarn = cfg.norm_eps, cfg.rope_scaling
     if m.q_lora_rank:
-        q = _rms(x @ params["w_dq"], params["q_norm"]) @ params["w_uq"]
+        q = _rms(x @ params["w_dq"], params["q_norm"], eps) @ params["w_uq"]
     else:
         q = x @ params["w_q"]
     q = q.reshape(B, S, h, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    c_kv = _rms(x @ params["w_dkv"], params["kv_norm"])       # [B,S,R]
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, yarn)
+    c_kv = _rms(x @ params["w_dkv"], params["kv_norm"], eps)  # [B,S,R]
     k_rope = apply_rope((x @ params["w_kr"])[:, :, None, :], positions,
-                        cfg.rope_theta)[:, :, 0, :]            # [B,S,dr]
+                        cfg.rope_theta, yarn)[:, :, 0, :]      # [B,S,dr]
     return q_nope, q_rope, c_kv, k_rope
 
 
-def mla_forward(params, x, positions, cfg: ModelConfig, *, chunk: int = 1024):
-    """Training/prefill MLA (unabsorbed). x: [B,S,D]."""
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(dn + dr)^-1/2, times YaRN's mscale(factor, mscale_all_dim)^2 where
+    the rope is YaRN-scaled (DeepSeek-V2's attention)."""
+    m: MLAConfig = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+def _splash_kernel(S: int, heads: int, block: int):
+    """Splash attention's causal kernel for [heads, S, .] operands, one
+    device, q and k blocks of ``block`` in the forward and both backward
+    kernels.  Made per trace: the kernel holds the mask's block map as
+    arrays of the trace it was made in."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+    mask = sm.MultiHeadMask([sm.CausalMask((S, S))] * heads)
+    sizes = sk.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        block_q_dq=block, block_kv_dq=block)
+    return sk.make_splash_mha_single_device(mask, block_sizes=sizes)
+
+
+def _splash_causal_attention(q, k, v, *, scale: float, block: int):
+    """q, k: [B,S,H,Dqk]; v: [B,S,H,Dv] -> [B,S,H,Dv].  Causal attention by
+    the Pallas TPU splash kernel, which takes a value head dim other than
+    the query/key one (MLA's 128 against 192).  The kernel applies no
+    scale, so q is scaled first."""
+    B, S, H, _ = q.shape
+    kernel = _splash_kernel(S, H, block)
+
+    def heads(x):                                    # [B,S,H,D]<->[B,H,S,D]
+        return jnp.swapaxes(x, 1, 2)
+
+    out = jax.vmap(kernel)(heads(q * jnp.asarray(scale, q.dtype)), heads(k),
+                           heads(v))
+    return heads(out)
+
+
+def mla_forward(params, x, positions, cfg: ModelConfig, *, chunk: int = 1024,
+                one_device: bool = False):
+    """Training/prefill MLA (unabsorbed). x: [B,S,D].
+
+    Causal attention takes the fused splash kernel where
+    ``flash_fallback_reason`` allows it (as GQA's fused path), else the
+    query-chunked float32 path below.  Each trace emits one ``kernel``
+    record (``op="flash_attention"``)."""
     m: MLAConfig = cfg.mla
     h = cfg.num_heads
     dn, dr, dv = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
@@ -324,8 +381,33 @@ def mla_forward(params, x, positions, cfg: ModelConfig, *, chunk: int = 1024):
     q_nope, q_rope, c_kv, k_rope = _mla_qkr(params, x, positions, cfg)
     k_nope = (c_kv @ params["w_uk"]).reshape(B, S, h, dn)
     v = (c_kv @ params["w_uv"]).reshape(B, S, h, dv)
-    scale = 1.0 / jnp.sqrt(dn + dr).astype(jnp.float32)
+    scale = mla_softmax_scale(cfg)
+    reason = flash_fallback_reason(S, causal=True, cross=False, window=0,
+                                   one_device=one_device, block=SPLASH_BLOCK)
+    from repro.kernels.ops import _emit_kernel   # Pallas: import on use
+    _emit_kernel(op="flash_attention", engaged=int(not reason),
+                 reason=reason, seq_len=S, heads=h, head_dim=dn + dr,
+                 head_dim_v=dv)
+    if not reason:
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            k_rope[:, :, None, :], (B, S, h, dr))], axis=-1)
+    with jax.named_scope("mla.attention"):
+        if not reason:
+            out = _splash_causal_attention(q, k, v, scale=scale,
+                                           block=SPLASH_BLOCK)
+        else:
+            out = _mla_chunked_attention(q_nope, q_rope, k_nope, k_rope, v,
+                                         scale=scale, chunk=chunk)
+    return out.reshape(B, S, h * dv) @ params["w_o"]
 
+
+def _mla_chunked_attention(q_nope, q_rope, k_nope, k_rope, v, *,
+                           scale: float, chunk: int):
+    """Causal MLA attention in query chunks of float32 scores against all
+    keys. q_nope, k_nope: [B,S,H,dn]; q_rope: [B,S,H,dr]; k_rope: [B,S,dr];
+    v: [B,S,H,dv] -> [B,S,H,dv]."""
+    B, S, h, dv = v.shape
     chunk = min(chunk, S)
     while S % chunk:
         chunk //= 2
@@ -344,11 +426,10 @@ def mla_forward(params, x, positions, cfg: ModelConfig, *, chunk: int = 1024):
         s = jnp.where(mask, s, NEG_INF)
         w = jax.nn.softmax(s, axis=-1)
         out = jnp.einsum("bhqs,bshd->bqhd", w, v.astype(jnp.float32))
-        return out.astype(x.dtype)
+        return out.astype(v.dtype)
 
     outs = jax.lax.map(one_chunk, jnp.arange(S // chunk))
-    out = jnp.moveaxis(outs, 0, 1).reshape(B, S, h * dv)
-    return out @ params["w_o"]
+    return jnp.moveaxis(outs, 0, 1).reshape(B, S, h, dv)
 
 
 def mla_init_cache(cfg: ModelConfig, batch: int, seq_len: int, n_layers: int,
@@ -384,7 +465,7 @@ def mla_decode(params, x, cache_ckv, cache_kr, pos, cfg: ModelConfig):
     s = jnp.einsum("bqhr,bsr->bhqs", q_eff, new_ckv.astype(jnp.float32))
     s += jnp.einsum("bqhd,bsd->bhqs", q_rope.astype(jnp.float32),
                     new_kr.astype(jnp.float32))
-    s /= jnp.sqrt(dn + dr)
+    s *= mla_softmax_scale(cfg)
     valid = jnp.arange(C) <= pos
     s = jnp.where(valid[None, None, None, :], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)                             # [B,h,1,C]

@@ -1,6 +1,8 @@
 """Shared neural-net building blocks (pure-functional, pytree params)."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -41,17 +43,51 @@ def layer_norm(params, x, eps: float = 1e-5):
 # ---------------------------------------------------------------------------
 
 
-def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def rope_frequencies(head_dim: int, theta: float, scaling=None) -> jax.Array:
+    """Inverse frequencies [Dh/2]; with ``scaling`` (a ``YarnConfig``),
+    YaRN's blend as DeepSeek-V2 computes it: the original frequencies below
+    the correction range, those divided by ``factor`` above it, a linear
+    ramp between."""
+    freq_extra = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
+                                             dtype=jnp.float32) / head_dim))
+    if scaling is None:
+        return freq_extra
+    freq_inter = freq_extra / scaling.factor
+    low, high = yarn_correction_range(head_dim, theta, scaling)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    mask = 1.0 - ramp
+    return freq_inter * (1.0 - mask) + freq_extra * mask
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [..., S, H, Dh]; positions: [..., S] (broadcastable)."""
+def yarn_correction_range(dim: int, theta: float, scaling) -> tuple:
+    """YaRN's (low, high) dimension bounds, clamped to [0, dim - 1]."""
+    def bound(beta):
+        return dim * math.log(scaling.original_max_position_embeddings
+                              / (beta * 2 * math.pi)) / (2 * math.log(theta))
+    low = max(math.floor(bound(scaling.beta_fast)), 0)
+    high = min(math.ceil(bound(scaling.beta_slow)), dim - 1)
+    return low, (high + 0.001 if high == low else high)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling=None) -> jax.Array:
+    """x: [..., S, H, Dh]; positions: [..., S] (broadcastable).  With
+    ``scaling`` (a ``YarnConfig``) the YaRN frequencies, and cos/sin
+    scaled by mscale / mscale_all_dim."""
     dh = x.shape[-1]
-    freqs = rope_frequencies(dh, theta)                       # [Dh/2]
+    freqs = rope_frequencies(dh, theta, scaling)              # [Dh/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., S, Dh/2]
     cos = jnp.cos(angles)[..., None, :]                        # [..., S, 1, Dh/2]
     sin = jnp.sin(angles)[..., None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -53,7 +53,7 @@ def _stack_init(fn, key, n):
 
 def _attn_op(bp, h, positions, cfg, **kw):
     if cfg.attention == "mla":
-        return attn.mla_forward(bp["attn"], h, positions, cfg)
+        return attn.mla_forward(bp["attn"], h, positions, cfg, **kw)
     return attn.gqa_forward(bp["attn"], h, positions, cfg, **kw)
 
 
@@ -69,8 +69,8 @@ def _moe_block(bp, x, positions, cfg: ModelConfig, one_device=False):
     h = nn.rms_norm(bp["ln1"], x, cfg.norm_eps)
     x = x + _attn_op(bp, h, positions, cfg, one_device=one_device)
     h = nn.rms_norm(bp["ln2"], x, cfg.norm_eps)
-    out, aux = moe_lib.moe_forward(bp["moe"], h, cfg)
-    return x + out, aux
+    out, aux, counts = moe_lib.moe_forward(bp["moe"], h, cfg, one_device)
+    return x + out, aux, counts
 
 
 def _mamba_block(bp, x, cfg: ModelConfig):
@@ -364,17 +364,20 @@ class Model:
                 dbody = self._ckpt(dbody, remat, remat_policy)
                 x, _ = jax.lax.scan(dbody, x, params["dense_blocks"])
 
-            block = _moe_block if cfg.moe is not None else _dense_block
-
             def body(carry, bp):
                 x, aux = carry
-                x, a = block(bp, x, positions, cfg, one_device)
-                return (x, aux + a), None
+                if cfg.moe is None:
+                    x, a = _dense_block(bp, x, positions, cfg, one_device)
+                    return (x, aux + a), None
+                x, a, counts = _moe_block(bp, x, positions, cfg, one_device)
+                return (x, aux + a), counts
 
             body = self._ckpt(body, remat, remat_policy)
-            (x, aux), _ = jax.lax.scan(
+            (x, aux), counts = jax.lax.scan(
                 body, (x, jnp.zeros((), jnp.float32)), params["blocks"])
             self._last_aux = aux
+            if counts is not None:     # tokens per held expert, all layers
+                self._last_expert_tokens = counts.sum(0)
 
         x = nn.rms_norm(params["final_norm"], x, cfg.norm_eps)
         return self._logits(params, x)
@@ -401,7 +404,10 @@ class Model:
             logits = logits[:, n_img:, :]
         ce = nn.cross_entropy(logits, labels, batch.get("mask"))
         aux = getattr(self, "_last_aux", jnp.zeros((), jnp.float32))
-        return ce + aux, {"ce": ce, "router_aux": aux}
+        stats = {"ce": ce, "router_aux": aux}
+        if cfg.moe is not None:
+            stats["expert_tokens"] = self._last_expert_tokens
+        return ce + aux, stats
 
     # -------------------------------------------------------------- cache --
 
@@ -519,7 +525,7 @@ class Model:
         if swiglu:
             x = x + nn.swiglu(bp["mlp"], h)
         else:
-            out, _ = moe_lib.moe_forward(bp["moe"], h, cfg)
+            out, *_ = moe_lib.moe_forward(bp["moe"], h, cfg)
             x = x + out
         return x, (nk, nv)
 
@@ -663,7 +669,7 @@ class Model:
                 x = x + out
                 h = nn.rms_norm(bp["ln2"], x, cfg.norm_eps)
                 if is_moe:
-                    out, _ = moe_lib.moe_forward(bp["moe"], h, cfg)
+                    out, *_ = moe_lib.moe_forward(bp["moe"], h, cfg)
                     x = x + out
                 else:
                     x = x + nn.swiglu(bp["mlp"], h)

@@ -161,8 +161,9 @@ register_schema(Schema(
 register_schema(Schema(
     "kernel", index="seq", description=(
         "kernel-dispatch record: backend chosen, block_d autotune "
-        "decision, analytic HBM traffic, attention path (emitted "
-        "host-side at trace time, once per (op, shape))"),
+        "decision, analytic HBM traffic, attention path, expert layer's "
+        "grouped matmul (emitted host-side at trace time, once per (op, "
+        "shape))"),
     fields=(
         Field("seq", "int", "dispatch sequence number"),
         Field("op", "str", "kernel op name"),
@@ -176,13 +177,19 @@ register_schema(Schema(
                                      "(roofline.round_pipeline_traffic)"),
         Field("hbm_bytes_ref", "scalar", "analytic reference-chain bytes"),
         Field("pld_passes", "int", "gradient-scale HBM round trips"),
-        Field("engaged", "int", "1 when the fused attention kernel took "
-                                "the call (flash_attention)"),
-        Field("reason", "str", "why the fused attention kernel did not "
-                               "take the call ('' when it did)"),
+        Field("engaged", "int", "1 when the fused kernel took the call "
+                                "on the chip (flash_attention, moe_gmm)"),
+        Field("reason", "str", "why the fused kernel did not take the "
+                               "call ('' when it did)"),
         Field("seq_len", "int", "attention sequence length"),
         Field("heads", "int", "attention query heads"),
-        Field("head_dim", "int", "attention head dim"),
+        Field("head_dim", "int", "attention head dim (q and k)"),
+        Field("head_dim_v", "int", "attention value head dim (MLA)"),
+        Field("experts_total", "int", "routed experts the router scores"),
+        Field("experts_held", "int", "routed experts held here"),
+        Field("top_k", "int", "experts per token"),
+        Field("tokens", "int", "tokens routed by the call"),
+        Field("buffer_rows", "int", "rows of the dispatch buffer"),
     )))
 
 register_schema(Schema(

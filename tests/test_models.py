@@ -164,13 +164,13 @@ def test_moe_row_dispatch_matches_global():
     cfg = get_config("deepseek-v2-lite-16b").reduced()
     E = cfg.moe.num_experts
     base = dataclasses.replace(cfg, moe=dataclasses.replace(
-        cfg.moe, capacity_factor=float(E)))
+        cfg.moe, capacity_factor=float(E), dispatch="global"))
     row = dataclasses.replace(cfg, moe=dataclasses.replace(
         cfg.moe, capacity_factor=float(E), dispatch="row"))
     p = moe_lib.moe_init(jax.random.PRNGKey(0), base, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (3, 32, cfg.d_model))
-    o1, _ = moe_lib.moe_forward(p, x, base)
-    o2, _ = moe_lib.moe_forward(p, x, row)
+    o1, *_ = moe_lib.moe_forward(p, x, base)
+    o2, *_ = moe_lib.moe_forward(p, x, row)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
 
 
@@ -181,7 +181,7 @@ def test_moe_capacity_drops_are_bounded():
     model = Model(cfg)
     p = moe_lib.moe_init(jax.random.PRNGKey(0), cfg, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, cfg.d_model))
-    out, aux = moe_lib.moe_forward(p, x, cfg)
+    out, aux, _ = moe_lib.moe_forward(p, x, cfg)
     assert out.shape == x.shape
     assert np.isfinite(np.asarray(out)).all()
     assert float(aux) >= 0.0

@@ -117,3 +117,60 @@ def test_flash_attention_compiles_for_v5e(one_chip, no_cache, grad):
     compiled = jax.jit(fwd_bwd if grad else fwd).lower(*args).compile()
     # forward: one kernel; backward: the forward with residuals, dk/dv, dq
     assert compiled.as_text().count("tpu_custom_call") >= (3 if grad else 1)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
+def test_latent_attention_compiles_for_v5e(one_chip, no_cache, grad):
+    """MLA's fused causal attention (splash) at DeepSeek-V2-Lite's widths
+    and its cell's rows: B=2, S=4096, 16 heads, q.k over 128 + 64 dims,
+    values 128, at the model's block."""
+    from repro.models import attention as attn
+    B, S, H = 2, 4096, 16
+    args = [_sds((B, S, H, 192), jnp.bfloat16, one_chip),
+            _sds((B, S, H, 192), jnp.bfloat16, one_chip),
+            _sds((B, S, H, 128), jnp.bfloat16, one_chip)]
+
+    def fwd(q, k, v):
+        return attn._splash_causal_attention(q, k, v, scale=0.1,
+                                             block=attn.SPLASH_BLOCK)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(fwd_bwd if grad else fwd).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= (3 if grad else 1)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
+def test_dropless_expert_layer_compiles_for_v5e(one_chip, no_cache, grad,
+                                                monkeypatch):
+    """The dropless expert layer at DeepSeek-V2-Lite's widths with the
+    chip's 8 of 64 experts, top-6, over one client's 2 x 4096 tokens,
+    under the step's vmap over servers: the grouped matmuls (megablox
+    gmm, and tgmm in the backward) lower for Mosaic."""
+    import dataclasses
+
+    from repro.configs.registry import get_config
+    from repro.models import moe as moe_lib
+    monkeypatch.setattr(moe_lib, "_on_tpu", lambda: True)
+    full = get_config("deepseek-v2-lite-16b")
+    cfg = dataclasses.replace(full, moe=dataclasses.replace(
+        full.moe, experts_held=8))
+    params = jax.eval_shape(
+        lambda k: moe_lib.moe_init(k, cfg, jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: _sds((1,) + s.shape, s.dtype, one_chip),
+                          params)
+    x = _sds((1, 2, 4096, cfg.d_model), jnp.bfloat16, one_chip)
+
+    def fwd(p, x):
+        return moe_lib.moe_forward(p, x, cfg, one_device=True)[0]
+
+    def fwd_bwd(p, x):
+        return jax.grad(lambda p, x: jnp.sum(fwd(p, x).astype(jnp.float32)),
+                        argnums=(0, 1))(p, x)
+
+    compiled = jax.jit(jax.vmap(fwd_bwd if grad else fwd)).lower(
+        params, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= (6 if grad else 3)
