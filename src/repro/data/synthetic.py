@@ -11,6 +11,7 @@ reproducible from (seed, server, client, step) without global state.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -29,6 +30,75 @@ def logistic_client_data(key, P: int, K: int, N: int, M: int,
     return feats, labels
 
 
+@functools.lru_cache(maxsize=16)
+def _successors(vocab: int, seed: int) -> jax.Array:
+    """The successor permutation, drawn and put on the device once."""
+    rng = np.random.default_rng(seed)
+    return jnp.asarray(rng.permutation(vocab), jnp.int32)
+
+
+@functools.lru_cache(maxsize=16)
+def _zipf_logits(vocab: int) -> jax.Array:
+    """Unigram logits ``-log rank``, computed by an eager device op: inside
+    a compiled program XLA could fold them at compile time, on the host."""
+    return -jnp.log(jnp.arange(1, vocab + 1, dtype=jnp.float32))
+
+
+@functools.lru_cache(maxsize=16)
+def _seed_key(seed: int) -> jax.Array:
+    return jax.random.PRNGKey(seed)
+
+
+def _rows(keys, succ, logits, per_client: int, n: int, bigram_frac: float):
+    """Token rows ``[C, per_client, n]`` for ``C`` client keys.
+
+    Each client draws a Zipf token per position and, with probability
+    ``bigram_frac``, takes the successor of the position before instead;
+    the successor chain is one scan over all ``C * per_client`` rows."""
+    def draws(key):
+        k1, k2, _ = jax.random.split(key, 3)
+        return (jax.random.categorical(k1, logits, shape=(per_client, n)),
+                jax.random.bernoulli(k2, bigram_frac, (per_client, n)))
+
+    d, use_bigram = jax.vmap(draws)(keys)
+    d, use_bigram = d.reshape(-1, n), use_bigram.reshape(-1, n)
+
+    def step(prev, inp):
+        di, ub = inp
+        tok = jnp.where(ub, succ[prev], di)
+        return tok, tok
+
+    first = d[:, 0]
+    _, toks = jax.lax.scan(step, first, (d[:, 1:].T, use_bigram[:, 1:].T))
+    toks = jnp.concatenate([first[:, None], toks.T], axis=1)
+    return toks.reshape(-1, per_client, n)
+
+
+@functools.partial(jax.jit, static_argnames=("per_client", "n",
+                                              "bigram_frac"))
+def _sample(keys, succ, logits, per_client, n, bigram_frac):
+    with jax.named_scope("gfl.input"):
+        return _rows(keys, succ, logits, per_client, n, bigram_frac)
+
+
+@functools.partial(jax.jit, static_argnames=("per_client", "seq_len",
+                                              "bigram_frac"))
+def _round_batches(key, step, ids, succ, logits, per_client, seq_len,
+                   bigram_frac):
+    """One round's ``{tokens, labels}``, ``[P, L, per_client, seq_len]``:
+    client (p, l) draws under ``fold_in(fold_in(fold_in(key, step), p),
+    ids[p, l])``."""
+    with jax.named_scope("gfl.input"):
+        P, L = ids.shape
+        base = jax.random.fold_in(key, step)
+        keys = jax.vmap(lambda p, cid: jax.random.fold_in(
+            jax.random.fold_in(base, p), cid))(
+                jnp.repeat(jnp.arange(P), L), ids.reshape(-1))
+        toks = _rows(keys, succ, logits, per_client, seq_len + 1,
+                     bigram_frac).reshape(P, L, per_client, seq_len + 1)
+        return {"tokens": toks[..., :-1], "labels": toks[..., 1:]}
+
+
 @dataclass(frozen=True)
 class TokenStream:
     """Synthetic LM distribution: zipf unigram mixed with a deterministic
@@ -37,30 +107,11 @@ class TokenStream:
     seed: int = 0
     bigram_frac: float = 0.5
 
-    def _succ_table(self):
-        rng = np.random.default_rng(self.seed)
-        return jnp.asarray(rng.permutation(self.vocab), jnp.int32)
-
     def sample(self, key, batch: int, seq_len: int) -> jax.Array:
-        with jax.named_scope("gfl.input"):
-            succ = self._succ_table()
-            k1, k2, k3 = jax.random.split(key, 3)
-            # zipf via exponential rank trick
-            ranks = jnp.arange(1, self.vocab + 1, dtype=jnp.float32)
-            logits = -jnp.log(ranks)
-            draws = jax.random.categorical(k1, logits, shape=(batch, seq_len))
-            use_bigram = jax.random.bernoulli(k2, self.bigram_frac,
-                                              (batch, seq_len))
-
-            def step(prev, inp):
-                d, ub = inp
-                tok = jnp.where(ub, succ[prev], d)
-                return tok, tok
-
-            first = draws[:, 0]
-            _, toks = jax.lax.scan(step, first,
-                                   (draws[:, 1:].T, use_bigram[:, 1:].T))
-            return jnp.concatenate([first[:, None], toks.T], axis=1)
+        return _sample(jnp.expand_dims(key, 0),
+                       _successors(self.vocab, self.seed),
+                       _zipf_logits(self.vocab), per_client=batch, n=seq_len,
+                       bigram_frac=self.bigram_frac)[0]
 
 
 def make_batch(stream: TokenStream, key, batch: int, seq_len: int) -> dict:
@@ -80,19 +131,17 @@ def federated_token_batches(stream: TokenStream, seed: int, step: int,
     :class:`~repro.core.population.CohortScheduler` samples it into;
     the default is the positional identity ``client_ids[p, l] = l``.
 
-    The build runs in the ``gfl.input`` span (``round=step``) and its
-    device work under the ``gfl.input`` scope."""
+    The whole round is one compiled program, built once per shape; the
+    round and the ids are its arguments.  The build runs in the
+    ``gfl.input`` span (``round=step``) and its device work under the
+    ``gfl.input`` scope."""
     from repro.telemetry import trace_span
-    with trace_span("gfl.input", round=step), jax.named_scope("gfl.input"):
-        base = jax.random.fold_in(jax.random.PRNGKey(seed), step)
-        if client_ids is not None:
-            client_ids = np.asarray(client_ids)
-
-        def client_batch(p, l):
-            cid = l if client_ids is None else int(client_ids[p, l])
-            k = jax.random.fold_in(jax.random.fold_in(base, p), cid)
-            return make_batch(stream, k, per_client, seq_len)
-
-        batches = [[client_batch(p, l) for l in range(L)] for p in range(P)]
-        return jax.tree.map(lambda *xs: jnp.stack(xs).reshape(
-            P, L, *xs[0].shape), *[b for row in batches for b in row])
+    with trace_span("gfl.input", round=step):
+        ids = (np.broadcast_to(np.arange(L, dtype=np.int32), (P, L))
+               if client_ids is None
+               else np.asarray(client_ids, np.int32).reshape(P, L))
+        return _round_batches(
+            _seed_key(seed), np.int32(step), ids,
+            _successors(stream.vocab, stream.seed),
+            _zipf_logits(stream.vocab), per_client=per_client,
+            seq_len=seq_len, bigram_frac=stream.bigram_frac)
